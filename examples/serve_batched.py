@@ -65,9 +65,11 @@ def main() -> None:
         outs = sess.generate(prompts, max_new=args.max_new)
         dt = time.time() - t0
     n_tok = sum(len(o) for o in outs)
+    dev = jax.devices()[0]
     print(f"arch={cfg.name} served {args.requests} requests "
           f"({n_tok} new tokens) in {dt:.2f}s -> {n_tok / dt:.1f} tok/s "
-          f"on 1 CPU core")
+          f"(host clock, compiles included) on {dev.platform} "
+          f"{dev.device_kind}")
     print(f"first completion: {outs[0][:10]}...")
     assert len(outs) == args.requests
 

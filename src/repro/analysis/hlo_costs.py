@@ -34,23 +34,9 @@ _COLL_RE = re.compile(
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns one dict; newer jax returns a list with one dict per
-    partition (length 1 for unsharded programs). Returns a single flat dict,
-    summing shared keys across partitions.
-    """
-    ca = compiled.cost_analysis() if callable(
-        getattr(compiled, "cost_analysis", None)) else compiled
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    out: Dict[str, float] = {}
-    for part in ca:
-        for k, v in part.items():
-            out[k] = out.get(k, 0.0) + v
-    return out
+    """``Compiled.cost_analysis()`` as a plain dict (the installed jax
+    returns one flat dict on the CPU and TPU backends alike)."""
+    return dict(compiled.cost_analysis())
 
 
 def compiled_cycles(compiled, *, flops_per_cycle: float = 2.0 * 128 * 128,

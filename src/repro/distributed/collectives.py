@@ -17,7 +17,6 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -67,5 +66,5 @@ def compressed_psum(x: jnp.ndarray, err: jnp.ndarray, mesh: Mesh,
         n = jax.lax.psum(jnp.ones(()), axis)
         return total / n, new_err
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                     out_specs=(P(axis), P(axis)))(x, err)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                         out_specs=(P(axis), P(axis)))(x, err)

@@ -19,7 +19,6 @@ from typing import Any, Callable, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.perf_model import LayerCost
@@ -97,10 +96,10 @@ def make_pipelined_fn(stage_fn: Callable, mesh: Mesh, *, n_stages: int,
 
         specs_p = jax.tree_util.tree_map(
             lambda _: P(stage_axis), stage_params)
-        stacked = shard_map(body, mesh=mesh,
-                            in_specs=(specs_p, P()),
-                            out_specs=P(stage_axis),
-                            check_rep=False)(stage_params, x)
+        stacked = jax.shard_map(body, mesh=mesh,
+                                in_specs=(specs_p, P()),
+                                out_specs=P(stage_axis),
+                                check_vma=False)(stage_params, x)
         return stacked[-1]                       # the last stage's outputs
 
     return pipelined

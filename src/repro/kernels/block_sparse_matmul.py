@@ -24,8 +24,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _build_tile_schedule_ref(mask: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Reference per-column-loop schedule builder — kept as the equivalence
@@ -155,7 +153,7 @@ def block_sparse_matmul(x: jnp.ndarray, w: jnp.ndarray,
             out_specs=pl.BlockSpec((bm, bn), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(counts, indices, x, w)

@@ -2,8 +2,8 @@
 
 Handle padding to block multiples, schedule construction from pruned weights,
 and backend selection (``interpret=True`` executes the kernel bodies in
-Python on CPU — the validation mode used by tests in this container; on a
-real TPU ``interpret=False`` compiles via Mosaic).
+Python on CPU — the validation mode of the CPU tests; on a real TPU
+``interpret=False`` compiles via Mosaic).
 """
 from __future__ import annotations
 

@@ -22,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(model: int = 1):
-    """Tiny mesh over however many (CPU) devices exist — tests/examples."""
+    """Tiny mesh over however many devices exist — tests/examples."""
     n = len(jax.devices())
     model = min(model, n)
     return jax.make_mesh((n // model, model), ("data", "model"))

@@ -127,7 +127,6 @@ def _shard_map_dispatch(x, gates, idx, p, moe: MoEConfig, act, act_tau):
         FFN pays).
     Returns None when the layout does not apply (no ctx / E % model != 0).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import ctx as _ctx
 
@@ -180,10 +179,10 @@ def _shard_map_dispatch(x, gates, idx, p, moe: MoEConfig, act, act_tau):
         return jax.lax.psum(y_part, "model")
 
     xspec = P(dp if dp else None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=c.mesh,
         in_specs=(xspec, xspec, xspec, wspec_in, wspec_in, wdspec_in),
-        out_specs=xspec, check_rep=False)(x, gates, idx, wg, wu, wd)
+        out_specs=xspec, check_vma=False)(x, gates, idx, wg, wu, wd)
 
 
 def capacity_for(T_local: int, moe: MoEConfig) -> int:
