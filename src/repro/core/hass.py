@@ -138,11 +138,11 @@ def hass_search(evaluate: Callable[[np.ndarray], Dict[str, float]],
     JSONL record per trial — proposal, score, metric terms, DSECache and
     engine-dispatch counter deltas, per-phase timings — plus run
     header/footer (DESIGN.md §18). Spans land in the process-global tracer
-    when one is installed (``repro.obs.use_tracer``). With neither, the
-    loop below is the literal uninstrumented seed path; with either,
-    instrumentation only reads clocks and counters, so the trial
-    transcript stays bit-identical in every state (gated in
-    ``benchmarks/obs_bench.py``).
+    when one is installed (``repro.obs.use_tracer``): the live phases
+    ``propose``/``evaluate``/``tell`` (no-op spans with tracing off) and a
+    post-hoc ``trial`` span per trial. Instrumentation only reads clocks
+    and counters, so the trial transcript stays bit-identical in every
+    state (gated in ``benchmarks/obs_bench.py``).
     """
     lambdas = Lambdas() if lambdas is None else lambdas
     dim = n_layers * (2 if include_act else 1)
@@ -191,9 +191,9 @@ def hass_search(evaluate: Callable[[np.ndarray], Dict[str, float]],
     if sync_lam:
         evaluate.lambdas = replace(lambdas)
 
-    # observability (DESIGN.md §18). ``obs`` off keeps the literal seed
-    # loops below; on, the instrumented twins time each phase and snapshot
-    # counter deltas — reads only, never a float the search computes.
+    # observability (DESIGN.md §18). The phase spans are no-ops with
+    # tracing off; ``obs`` on also times each phase and snapshots counter
+    # deltas — reads only, never a float the search computes.
     tr = get_tracer()
     obs = tr.enabled or recorder is not None
     clk = tr.now if tr.enabled else time.perf_counter
@@ -207,13 +207,11 @@ def hass_search(evaluate: Callable[[np.ndarray], Dict[str, float]],
         """Record trial ``result.trials[k]``. Batched rounds pass the whole
         round's window to every member but attribute the shared phase time
         and counter deltas to the FIRST trial only (zeros elsewhere), so
-        footer totals stay the sum of per-trial records."""
+        footer totals stay the sum of per-trial records. The phases
+        themselves are live ``propose``/``evaluate``/``tell`` spans opened
+        by the loops."""
         if tr.enabled:
             tr.add_span("trial", t0, t3, depth=0, i=k)
-            if first:
-                tr.add_span("propose", t0, t1, depth=1)
-                tr.add_span("evaluate", t1, t2, depth=1)
-                tr.add_span("tell", t2, t3, depth=1)
         if recorder is not None:
             c1, e1 = _snap()
             zero = {"propose": 0.0, "evaluate": 0.0, "tell": 0.0}
@@ -253,30 +251,33 @@ def hass_search(evaluate: Callable[[np.ndarray], Dict[str, float]],
             if obs:
                 snap = _snap()
                 t0 = clk()
-            m = dict(evaluate(xa))
-            opt.tell(xa, record(xa, m))
+            with tr.span("evaluate"):           # the seed's tell included
+                m = dict(evaluate(xa))
+                opt.tell(xa, record(xa, m))
             if obs:
                 t3 = clk()
                 _observe(0, t0, t0, t3, t3, snap)
             n0 = 1
         if batch_size is None:
-            if not obs:
-                for it in range(max(iters - n0, 0)):
-                    x = opt.ask()
-                    m = dict(evaluate(x))
-                    opt.tell(x, record(x, m))
-                return result
             for it in range(max(iters - n0, 0)):
-                snap = _snap()
-                t0 = clk()
-                x = opt.ask()
-                t1 = clk()
-                m = dict(evaluate(x))
-                t2 = clk()
-                opt.tell(x, record(x, m))
-                t3 = clk()
-                _observe(len(result.trials) - 1, t0, t1, t2, t3, snap)
-            _finish_obs()
+                if obs:
+                    snap = _snap()
+                    t0 = clk()
+                with tr.span("propose"):
+                    x = opt.ask()
+                if obs:
+                    t1 = clk()
+                with tr.span("evaluate"):
+                    m = dict(evaluate(x))
+                if obs:
+                    t2 = clk()
+                with tr.span("tell"):
+                    opt.tell(x, record(x, m))
+                if obs:
+                    t3 = clk()
+                    _observe(len(result.trials) - 1, t0, t1, t2, t3, snap)
+            if obs:
+                _finish_obs()
             return result
 
         if batch_size < 1:
@@ -288,15 +289,18 @@ def hass_search(evaluate: Callable[[np.ndarray], Dict[str, float]],
             if obs:
                 snap = _snap()
                 t0 = clk()
-            xs = opt.ask_batch(k, liar=liar)
+            with tr.span("propose"):
+                xs = opt.ask_batch(k, liar=liar)
             if obs:
                 t1 = clk()
-            ms = [dict(m) for m in eval_batch(xs)] \
-                if eval_batch is not None and k > 1 \
-                else [dict(evaluate(x)) for x in xs]
+            with tr.span("evaluate"):
+                ms = [dict(m) for m in eval_batch(xs)] \
+                    if eval_batch is not None and k > 1 \
+                    else [dict(evaluate(x)) for x in xs]
             if obs:
                 t2 = clk()
-            opt.tell_batch(xs, [record(x, m) for x, m in zip(xs, ms)])
+            with tr.span("tell"):
+                opt.tell_batch(xs, [record(x, m) for x, m in zip(xs, ms)])
             if obs:
                 t3 = clk()
                 base = len(result.trials) - k
@@ -1265,20 +1269,27 @@ class CNNEvaluator:
                                                (pad,) + codes_j.shape[1:])])
             self.padded_batches += 1
         self.batch_shapes.add(int(s_w.shape[0]))
-        if pattern_eval:
-            accs, sw_meas, sa_meas, swt_meas = map(
-                np.asarray,
-                self._eval_p_batch(self.params, s_w, s_a, codes_j))
-        else:
-            accs, sw_meas, sa_meas, swt_meas = map(
-                np.asarray, self._eval_batch(self.params, s_w, s_a))
-        if B > 1 and self.dse_cache is not None and self.batch_dse \
-                and self.dse_engine == "auto":
-            return self._metrics_batch(accs[:B], sw_meas[:B], sa_meas[:B],
-                                       swt_meas[:B] if self.tiled else None,
-                                       codes_rows=codes_rows)
-        return [self._metrics(float(accs[b]), sw_meas[b], sa_meas[b],
-                              swt_meas[b] if self.tiled else None,
-                              codes=codes_rows[b]
-                              if codes_rows is not None else None)
-                for b in range(B)]
+        tr = get_tracer()
+        # evaluate.forward: dispatch of the vmapped prune+forward until its
+        # outputs are host arrays (the wait for the device included)
+        with tr.span("evaluate.forward"):
+            if pattern_eval:
+                accs, sw_meas, sa_meas, swt_meas = map(
+                    np.asarray,
+                    self._eval_p_batch(self.params, s_w, s_a, codes_j))
+            else:
+                accs, sw_meas, sa_meas, swt_meas = map(
+                    np.asarray, self._eval_batch(self.params, s_w, s_a))
+        # evaluate.dse: the DSE and frontier scoring on the host
+        with tr.span("evaluate.dse"):
+            if B > 1 and self.dse_cache is not None and self.batch_dse \
+                    and self.dse_engine == "auto":
+                return self._metrics_batch(
+                    accs[:B], sw_meas[:B], sa_meas[:B],
+                    swt_meas[:B] if self.tiled else None,
+                    codes_rows=codes_rows)
+            return [self._metrics(float(accs[b]), sw_meas[b], sa_meas[b],
+                                  swt_meas[b] if self.tiled else None,
+                                  codes=codes_rows[b]
+                                  if codes_rows is not None else None)
+                    for b in range(B)]

@@ -1,9 +1,12 @@
 """Unified telemetry (DESIGN.md §18): span tracing, counters, and the
-search flight recorder — zero-dependency, no-op by default.
+search flight recorder — no-op by default; JAX is imported only when a
+tracer is installed.
 
-``repro.obs.trace``    — ``Tracer`` (nested spans / counters / gauges /
-                         histograms), the process-global no-op default,
-                         Chrome trace-event + flat metrics exporters.
+``repro.obs.trace``    — ``Tracer`` (nested spans, counters, gauges,
+                         compile counts by span; installed, its spans
+                         are ``jax.profiler`` annotations), the
+                         process-global no-op default, Chrome trace-event
+                         + flat metrics exporters.
 ``repro.obs.recorder`` — ``FlightRecorder``: one structured JSONL record
                          per search trial plus run header/footer.
 ``repro.obs.log``      — tiny level-filtered logger routed through the

@@ -1,20 +1,30 @@
-"""Span tracing, counters, gauges, and histograms (DESIGN.md §18).
+"""Span tracing, counters, and gauges (DESIGN.md §18).
 
 The process-global tracer defaults to ``NULL_TRACER``, whose ``enabled``
 attribute is ``False`` — instrumented code guards every emission behind
-``if tr.enabled`` (or never branches at all: the search loop keeps its
-uninstrumented hot loop verbatim when tracing is off), so the disabled
-path pays one attribute check and stays IEEE-bit-identical to the
-pre-telemetry build. Instrumentation only *reads* clocks and counters; it
-never touches a float any engine computes, so the enabled path is
-bit-identical too (gated in ``benchmarks/obs_bench.py``).
+``if tr.enabled`` or opens the shared no-op ``_NullSpan``, so the disabled
+path pays one attribute check or one empty ``with`` and stays
+IEEE-bit-identical to the pre-telemetry build. Instrumentation only
+*reads* clocks and counters; it never touches a float any engine
+computes, so the enabled path is bit-identical too (gated in
+``benchmarks/obs_bench.py``).
+
+While an enabled tracer is installed (``set_tracer``/``use_tracer``):
+
+* every ``Tracer.span`` also opens a ``jax.profiler.TraceAnnotation`` of
+  the same name, so under a running ``jax.profiler`` trace the span lands
+  on the host plane, on its thread's line, stamped by the same clock as
+  the device's events;
+* every XLA compile (``/jax/core/compile/backend_compile_duration``, which
+  a load from the persistent compile cache also emits) counts as
+  ``compiles:<innermost open span>`` (the span part empty when none is
+  open). The listener is registered on install and removed on uninstall.
 
 The clock is injectable (``Tracer(clock=...)``) so tests run on fake time.
 Exporters: ``to_chrome_trace``/``export_chrome_trace`` emit Chrome
 trace-event JSON (``{"traceEvents": [...]}`` with "X" complete events —
 loadable in Perfetto / ``chrome://tracing``); ``metrics``/
-``export_metrics`` emit one flat JSON of counters, gauges, and histogram
-summaries.
+``export_metrics`` emit one flat JSON of counters and gauges.
 """
 from __future__ import annotations
 
@@ -93,9 +103,6 @@ class NullTracer:
     def gauge(self, name: str, value: float) -> None:
         pass
 
-    def observe(self, name: str, value: float) -> None:
-        pass
-
     def instant(self, name: str, **args) -> None:
         pass
 
@@ -107,7 +114,7 @@ class _Span:
     """An open span: records its end time and pops itself off the tracer's
     stack on ``__exit__``. Exceptions propagate (the span still closes)."""
 
-    __slots__ = ("_tr", "name", "t0", "depth", "args")
+    __slots__ = ("_tr", "name", "t0", "depth", "args", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, args: dict):
         self._tr = tr
@@ -115,31 +122,42 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
-        self.depth = len(self._tr._stack)
-        self._tr._stack.append(self)
-        self.t0 = self._tr.now()
+        tr = self._tr
+        self._ann = None if tr._annotation is None \
+            else tr._annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.depth = len(tr._stack)
+        tr._stack.append(self)
+        self.t0 = tr.now()
         return self
 
     def __exit__(self, *exc) -> bool:
-        t1 = self._tr.now()
-        self._tr._stack.pop()
-        self._tr._finish(self.name, self.t0, t1, self.depth, self.args)
+        tr = self._tr
+        t1 = tr.now()
+        tr._stack.pop()
+        tr._finish(self.name, self.t0, t1, self.depth, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
 class Tracer:
-    """Collects nested spans, counters, gauges, and histograms in-process.
+    """Collects nested spans, counters, and gauges in-process.
 
     * ``span(name, **args)`` — context manager; nesting depth comes from
-      the tracer's open-span stack, start/end from its clock.
+      the tracer's open-span stack, start/end from its clock. Installed,
+      the span is also a ``jax.profiler.TraceAnnotation``.
     * ``add_span(name, t0, t1, depth=0, **args)`` — record a span whose
-      times the caller already measured (the search loop reads the clock
-      inline so its per-trial overhead is four clock reads, not four
-      context-manager frames).
-    * ``count(name, n)`` / ``gauge(name, v)`` / ``observe(name, v)`` —
-      monotonic counters, last-value gauges, and min/max/sum/count
-      histogram summaries. ``instant(name, **args)`` records a
+      times the caller already measured (the search loop's per-trial
+      ``trial`` span). Having no live extent, it never reaches a profiler
+      trace.
+    * ``count(name, n)`` / ``gauge(name, v)`` — monotonic counters and
+      last-value gauges. ``instant(name, **args)`` records a
       zero-duration marker event.
+
+    Events are appended as they finish, so a live span's parent is the
+    first later live span one ``depth`` shallower.
 
     Timestamps are whatever the injected ``clock`` returns (seconds by
     default); the Chrome exporter scales to microseconds.
@@ -153,7 +171,8 @@ class Tracer:
         self._stack: List[_Span] = []
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
-        self.hists: Dict[str, Dict[str, float]] = {}
+        # jax.profiler.TraceAnnotation while installed, else None
+        self._annotation = None
 
     # ----------------------------------------------------------------- #
     def now(self) -> float:
@@ -182,19 +201,6 @@ class Tracer:
     def gauge(self, name: str, value: float) -> None:
         self.gauges[name] = value
 
-    def observe(self, name: str, value: float) -> None:
-        h = self.hists.get(name)
-        if h is None:
-            self.hists[name] = {"count": 1, "sum": value,
-                                "min": value, "max": value}
-        else:
-            h["count"] += 1
-            h["sum"] += value
-            if value < h["min"]:
-                h["min"] = value
-            if value > h["max"]:
-                h["max"] = value
-
     # ----------------------------------------------------------------- #
     def to_chrome_trace(self, pid: int = 0, tid: int = 0) -> dict:
         """Chrome trace-event JSON: one "X" (complete) event per finished
@@ -220,8 +226,7 @@ class Tracer:
 
     def metrics(self) -> dict:
         return {"counters": dict(self.counters),
-                "gauges": dict(self.gauges),
-                "histograms": {k: dict(v) for k, v in self.hists.items()}}
+                "gauges": dict(self.gauges)}
 
     def export_metrics(self, path: str) -> str:
         with open(path, "w") as f:
@@ -233,6 +238,13 @@ class Tracer:
 # process-global tracer
 # --------------------------------------------------------------------- #
 _TRACER = NULL_TRACER
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    if event == COMPILE_EVENT:
+        stack = _TRACER._stack
+        _TRACER.count("compiles:" + (stack[-1].name if stack else ""))
 
 
 def get_tracer():
@@ -242,10 +254,23 @@ def get_tracer():
 
 def set_tracer(tracer) -> object:
     """Install ``tracer`` (or ``None`` for the no-op default) process-wide;
-    returns the previous tracer so callers can restore it."""
+    returns the previous tracer so callers can restore it. Installing an
+    enabled tracer turns on its profiler annotations and registers the
+    compile listener; removing it undoes both."""
     global _TRACER
     prev = _TRACER
-    _TRACER = NULL_TRACER if tracer is None else tracer
+    new = NULL_TRACER if tracer is None else tracer
+    if prev is new:
+        return prev
+    if prev.enabled:
+        from jax import monitoring
+        monitoring.unregister_event_duration_listener(_on_compile)
+        prev._annotation = None
+    _TRACER = new
+    if new.enabled:
+        import jax
+        new._annotation = jax.profiler.TraceAnnotation
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
     return prev
 
 

@@ -226,17 +226,27 @@ class ServeSession:
             toks[j, :len(p)] = p
         if ragged:
             kw = dict(kw, prompt_lens=jnp.asarray(lens, jnp.int32))
-        logits, cache = self.api.prefill(self.params, jnp.asarray(toks),
-                                         self.S_max, **kw)
+        # serve.prefill: host time to trace, lower and dispatch one prefill;
+        # it does not wait for the device
+        with get_tracer().span("serve.prefill"):
+            logits, cache = self.api.prefill(self.params, jnp.asarray(toks),
+                                             self.S_max, **kw)
         return logits, cache, None
 
     def _decode_tokens(self, logits, cache, max_new: int) -> List[List[int]]:
-        cur = self._sample(logits)
-        gen = [cur]
-        for _ in range(max_new - 1):
-            logits, cache = self._decode(self.params, cache, cur)
+        tr = get_tracer()
+        steps = max_new - 1
+        # serve.decode: dispatch of the decode steps and their sampling,
+        # up to the one readback after the loop
+        with tr.span("serve.decode"):
             cur = self._sample(logits)
-            gen.append(cur)
+            gen = [cur]
+            for _ in range(steps):
+                logits, cache = self._decode(self.params, cache, cur)
+                cur = self._sample(logits)
+                gen.append(cur)
+        if tr.enabled:
+            tr.count("serve.decode_steps", steps)
         seq = np.concatenate([np.asarray(g) for g in gen], axis=1)
         return [list(map(int, row)) for row in seq]
 

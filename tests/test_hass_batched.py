@@ -214,3 +214,45 @@ def test_hardware_aware_flag_respected_in_batched_scores():
         m = t.metrics
         assert t.score == pytest.approx(
             m["acc"] + 0.3 * m["spa"] + 0.5 * m["thr_norm"] - 0.3 * m["dsp"])
+
+
+def test_batched_cnn_search_splits_evaluate_into_forward_and_dse():
+    """Each wave's ``evaluate`` span holds exactly one ``evaluate.forward``
+    (the vmapped prune+forward until host arrays) and one ``evaluate.dse``
+    (the batched DSE), and tracing leaves the transcript bit-identical."""
+    import jax
+    from repro.configs import reduce_config
+    from repro.configs.paper_cnns import RESNET18
+    from repro.core.hass import CNNEvaluator
+    from repro.core.perf_model import TPUModel
+    from repro.models import cnn
+    from repro.obs import Tracer, use_tracer
+
+    cfg = reduce_config(RESNET18)
+    rng = jax.random.PRNGKey(0)
+    params = cnn.init_params(cfg, rng)
+    images = jax.random.normal(rng, (4, cfg.img_res, cfg.img_res, 3))
+    tpu = TPUModel()
+    ev = CNNEvaluator(cfg, params, images, tpu, budget=tpu.chip_budget,
+                      dse_iters=60)
+    L = len(ev.prunable)
+    kw = dict(iters=8, s_max=0.9, seed=3, batch_size=4, liar="min")
+    off = hass_search(ev, L, **kw)
+    with use_tracer(Tracer()) as tr:
+        on = hass_search(ev, L, **kw)
+    assert len(on.trials) == len(off.trials) == 8
+    for a, b in zip(off.trials, on.trials):
+        assert np.array_equal(a.x, b.x)
+        assert a.score == b.score and a.metrics == b.metrics
+    assert on.best_score == off.best_score
+
+    # events are appended as they finish: each wave's two children, one
+    # level deeper, come right before their ``evaluate``
+    names = [(e["name"], e["depth"]) for e in tr.events
+             if e["name"].startswith("evaluate")]
+    assert names == [("evaluate.forward", 1), ("evaluate.dse", 1),
+                     ("evaluate", 0)] * 2      # two waves of four
+    evs = [e for e in tr.events if e["name"].startswith("evaluate")]
+    for fwd, dse, outer in zip(evs[::3], evs[1::3], evs[2::3]):
+        assert outer["t0"] <= fwd["t0"] <= fwd["t1"] <= dse["t0"] \
+            <= dse["t1"] <= outer["t1"]

@@ -115,14 +115,115 @@ def test_tracer_counters_gauges_histograms():
     tr.count("n")
     tr.count("n", 4)
     tr.gauge("g", 2.5)
-    for v in (1.0, 3.0, 2.0):
-        tr.observe("h", v)
     m = tr.metrics()
     assert m["counters"]["n"] == 5
     assert m["gauges"]["g"] == 2.5
-    h = m["histograms"]["h"]
-    assert h["count"] == 3 and h["sum"] == 6.0
-    assert h["min"] == 1.0 and h["max"] == 3.0
+    assert set(m) == {"counters", "gauges"}     # no histograms
+
+
+def _parent(events, i):
+    """The parent of live span ``events[i]``: the first later event one
+    depth shallower (events are appended as they finish)."""
+    d = events[i]["depth"]
+    if d == 0:
+        return None
+    return next(j for j in range(i + 1, len(events))
+                if events[j]["depth"] == d - 1)
+
+
+def test_spans_record_their_parent():
+    """Spans record their nesting: depth plus finish order names each
+    live span's parent, whose interval holds the child's."""
+    t = iter(range(1, 100))
+    tr = Tracer(clock=lambda: float(next(t)))
+    with tr.span("a"):
+        with tr.span("b"):
+            with tr.span("c"):
+                pass
+            tr.instant("mark")
+        with tr.span("d"):
+            pass
+    ev = tr.events
+    by = {e["name"]: i for i, e in enumerate(ev)}
+    parent = {e["name"]: _parent(ev, i) for i, e in enumerate(ev)}
+    assert parent == {"c": by["b"], "mark": by["b"], "b": by["a"],
+                      "d": by["a"], "a": None}
+    for i, e in enumerate(ev):
+        p = parent[e["name"]]
+        if p is not None:
+            assert ev[p]["t0"] < e["t0"] <= e["t1"] < ev[p]["t1"]
+
+
+def _xplane(log_dir):
+    import glob
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return path
+
+
+def test_installed_spans_land_on_the_profiler_host_plane(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    with use_tracer(Tracer()) as tr:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("obs.outer"):
+                with tr.span("obs.inner"):
+                    sum(range(20000))
+        finally:
+            jax.profiler.stop_trace()
+    with tr.span("obs.uninstalled"):        # a plain tracer annotates nothing
+        pass
+    prof = ProfileData.from_file(_xplane(str(tmp_path)))
+    (host,) = [p for p in prof.planes if p.name == "/host:CPU"]
+    got = {}
+    for line in host.lines:
+        for e in line.events:
+            if e.name.startswith("obs."):
+                got.setdefault(e.name, []).append(
+                    (line.name, e.start_ns, e.start_ns + e.duration_ns))
+    assert set(got) == {"obs.outer", "obs.inner"}
+    ((line_o, o0, o1),), ((line_i, i0, i1),) = got["obs.outer"], \
+        got["obs.inner"]
+    assert line_o == line_i                 # the same thread's line
+    assert o0 <= i0 < i1 <= o1
+
+
+def test_compiles_are_counted_by_innermost_span():
+    import jax
+    import jax.numpy as jnp
+    x = jnp.arange(7.0)
+    f = jax.jit(lambda v: v * 3.0 + 1.0)
+    with use_tracer(Tracer()) as tr:
+        with tr.span("outer"):
+            with tr.span("first"):
+                f(x).block_until_ready()
+            n = dict(tr.counters)
+            with tr.span("second"):
+                f(x).block_until_ready()
+    assert n.get("compiles:first") == 1
+    assert "compiles:outer" not in n
+    assert "compiles:second" not in tr.counters
+    assert tr.counters == n                 # the second call compiled nothing
+
+
+def test_no_monitoring_listener_outlives_use_tracer():
+    from jax._src import monitoring
+    from repro.obs import trace as obs_trace
+
+    def ours():
+        return monitoring.get_event_duration_listeners().count(
+            obs_trace._on_compile)
+
+    assert ours() == 0
+    with use_tracer(Tracer()):
+        assert ours() == 1
+        with use_tracer(Tracer()):          # nested: still one
+            assert ours() == 1
+        assert ours() == 1
+    assert ours() == 0
+    with use_tracer(NULL_TRACER):
+        assert ours() == 0
 
 
 def test_flight_recorder_roundtrip_and_footer_sums(tmp_path):

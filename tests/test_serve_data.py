@@ -43,6 +43,21 @@ def test_serve_batching_chunks_requests():
     assert len(outs) == 5
 
 
+def test_generate_emits_one_prefill_and_one_decode_span_per_chunk():
+    from repro.obs import Tracer, use_tracer
+    api = build_model(CFG)
+    params = api.init(RNG)
+    prompts = [np.arange(6) for _ in range(5)]
+    sess = ServeSession(api, params, batch_slots=2, S_max=16)
+    off = sess.generate(prompts, max_new=4)
+    with use_tracer(Tracer()) as tr:
+        on = sess.generate(prompts, max_new=4)
+    assert on == off
+    names = [e["name"] for e in tr.events if e["name"].startswith("serve.")]
+    assert names == ["serve.prefill", "serve.decode"] * 3    # 3 chunks
+    assert tr.counters["serve.decode_steps"] == 3 * (4 - 1)
+
+
 def test_pipeline_prefetch_and_cursor():
     shape = ShapeConfig("t", 16, 4, "train")
     p1 = DataPipeline(CFG, shape, seed=5, start_step=0, prefetch=2)
