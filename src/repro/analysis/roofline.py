@@ -90,11 +90,10 @@ def analytic_traffic(cfg, shape, *, params_bytes: float, opt_bytes: float = 0,
                      remat: bool = True) -> Dict[str, float]:
     """Modeled per-step global HBM traffic (bytes), by component.
 
-    Assumptions (stated in EXPERIMENTS.md): flash-style attention keeps
+    Assumptions: flash-style attention keeps
     per-block score temporaries in VMEM; weights are re-read from HBM per
-    microbatch (fwd + remat-fwd + bwd); the baseline decode cache write is a
-    full-cache jnp.where (read+write whole cache) — a deliberate baseline
-    inefficiency that §Perf hillclimbs away.
+    microbatch (fwd + remat-fwd + bwd); a decode step reads the whole cache
+    once and writes one row per sequence per layer in place (negligible).
     """
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
     d, L, V = cfg.d_model, cfg.num_layers, cfg.vocab_size
@@ -116,7 +115,7 @@ def analytic_traffic(cfg, shape, *, params_bytes: float, opt_bytes: float = 0,
         t["logits"] = 2.0 * shape.global_batch * V * 2
     else:                                                # decode
         t["weights"] = params_bytes
-        t["cache"] = 2.0 * cache_bytes                   # full r+w (baseline)
+        t["cache"] = cache_bytes                         # one full read
         t["logits"] = 2.0 * shape.global_batch * V * 2
         t["activations"] = 8.0 * shape.global_batch * d * L * 2
     t["total"] = sum(t.values())

@@ -81,12 +81,12 @@ def train_tcfg(arch: str) -> TrainConfig:
 class CellTuning:
     def __init__(self, accum=None, cast_bf16=False, no_fsdp=False,
                  embed_tp=False, opt_dtype=None, attn_impl=None,
-                 cache_scatter=False, moe_shard_cap=False,
+                 moe_shard_cap=False,
                  grad_dtype=None, dp_all=False, remat="keep",
                  moe_shardmap=False):
         self.accum, self.cast_bf16, self.no_fsdp = accum, cast_bf16, no_fsdp
         self.embed_tp, self.opt_dtype = embed_tp, opt_dtype
-        self.attn_impl, self.cache_scatter = attn_impl, cache_scatter
+        self.attn_impl = attn_impl
         self.moe_shard_cap, self.grad_dtype = moe_shard_cap, grad_dtype
         self.remat = remat            # "keep" | None | "full" | "dots"
         self.moe_shardmap = moe_shardmap
@@ -112,9 +112,8 @@ TUNINGS = {
     # re-gathers; a per-shard shard_map quantizer would be needed. §Perf.)
     ("deepseek-v3-671b", "train_4k"): CellTuning(
         accum=4, cast_bf16=True, moe_shardmap=True, grad_dtype="bfloat16"),
-    # serving: TP-only weights (no per-token FSDP gather) + scatter cache
-    ("deepseek-67b", "decode_32k"): CellTuning(
-        no_fsdp=True, cache_scatter=True),
+    # serving: TP-only weights (no per-token FSDP gather)
+    ("deepseek-67b", "decode_32k"): CellTuning(no_fsdp=True),
 }
 
 
@@ -129,7 +128,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     api = build_model(cfg)
     specs = input_specs(cfg, shape)
     t = tuning or CellTuning()
-    os.environ["REPRO_CACHE_SCATTER"] = "1" if t.cache_scatter else "0"
     os.environ["REPRO_MOE_SHARD_CAP"] = "1" if t.moe_shard_cap else "0"
     os.environ["REPRO_MOE_SHARDMAP"] = "1" if t.moe_shardmap else "0"
     spec_kw = dict(no_fsdp=t.no_fsdp, embed_tp=t.embed_tp)
@@ -231,11 +229,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                          out_shardings=(None, _ns(mesh, c_specs)),
                          donate_argnums=1)
             lowered = fn.lower(params_shape, specs["cache"], specs["token"])
-            cache_traffic_scale = 1.0 if t.cache_scatter else 2.0
             traffic = analytic_traffic(
                 cfg, shape, params_bytes=_tree_bytes(params_shape),
-                cache_bytes=_tree_bytes(specs["cache"]) *
-                cache_traffic_scale / 2.0)
+                cache_bytes=_tree_bytes(specs["cache"]))
     return lowered, "", traffic
 
 

@@ -121,8 +121,10 @@ def init_params(cfg: ModelConfig, rng) -> Params:
 
 
 def _shared_attn(cfg, params, h, h0, positions, cache=None, pos=None,
-                 window=0, return_kv_eff=0):
-    """Apply the weight-tied attention block. cache: per-invocation KV.
+                 window=0, return_kv_eff=0, site=0):
+    """Apply the weight-tied attention block. cache: the KV of every
+    invocation site, (n_sites, B, S, KV, hd); decode writes site ``site``'s
+    rows into it and returns it whole.
     return_kv_eff>0 (train path): also return the last ``eff`` K/V rows,
     right-padded — the prefill cache for this invocation site."""
     dt = h.dtype
@@ -144,8 +146,8 @@ def _shared_attn(cfg, params, h, h0, positions, cache=None, pos=None,
                 return jnp.pad(a, pad)
             new_cache = {"k": to_cache(kk), "v": to_cache(vv)}
     else:
-        o, new_cache = tfm._gqa_decode_attn(p["attn"], x, cfg, cache, pos,
-                                            window)
+        o, new_cache = tfm._gqa_decode_attn(p["attn"], x, cfg, cache, site,
+                                            pos, window)
     x2 = rmsnorm(z + o, p["ln2"], cfg.norm_eps)
     y, _ = tfm.ffn_block(p["ffn"], x2, cfg)
     return h + z + o + y, new_cache
@@ -277,14 +279,12 @@ def decode_step(cfg: ModelConfig, params, state, token):
 
     groups = [(g * k, min((g + 1) * k, L)) for g in range(_n_shared(cfg))] \
         if k else [(0, L)]
-    new_conv, new_ssm, new_ak, new_av = [], [], [], []
+    new_conv, new_ssm = [], []
+    attn = {"k": state["attn_k"], "v": state["attn_v"]} if k else None
     for gi, (lo, hi) in enumerate(groups):
         if k:
-            cache = {"k": state["attn_k"][gi], "v": state["attn_v"][gi]}
-            h, nc = _shared_attn(cfg, params, h, h0, None, cache=cache,
-                                 pos=pos, window=4096)
-            new_ak.append(nc["k"])
-            new_av.append(nc["v"])
+            h, attn = _shared_attn(cfg, params, h, h0, None, cache=attn,
+                                   pos=pos, window=4096, site=gi)
         sub_p = jax.tree_util.tree_map(lambda a: a[lo:hi], params["mamba"])
         sub_st = {"conv": state["conv"][lo:hi], "ssm": state["ssm"][lo:hi]}
         h, sts = maybe_scan(mamba_step, h, (sub_p, sub_st), length=hi - lo)
@@ -294,8 +294,7 @@ def decode_step(cfg: ModelConfig, params, state, token):
     new_state["conv"] = jnp.concatenate(new_conv, axis=0)
     new_state["ssm"] = jnp.concatenate(new_ssm, axis=0)
     if k:
-        new_state["attn_k"] = jnp.stack(new_ak)
-        new_state["attn_v"] = jnp.stack(new_av)
+        new_state["attn_k"], new_state["attn_v"] = attn["k"], attn["v"]
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     w = params["embed"].T if cfg.tied_embeddings else params["lm_head"]
     return h @ w.astype(dt), new_state

@@ -386,26 +386,26 @@ def init_cache(cfg: ModelConfig, B: int, S_max: int) -> Params:
     return cache
 
 
-def _cache_write(buf, new, lens):
-    """buf (B,S,...), new (B,1,...): write at position lens[b] per sequence.
+def _cache_write(buf, i, new, slot):
+    """buf (L,B,S,...), new (B,1,...): write ``new[b]`` into layer ``i`` at
+    position ``slot[b]`` of each sequence b. Returns the updated buffer and
+    layer i's (B,S,...) slice read from it.
 
-    Baseline: jnp.where over the full cache (reads+writes the whole buffer —
-    2x cache HBM traffic). REPRO_CACHE_SCATTER=1 switches to a row scatter
-    (writes only B rows) — a §Perf memory-term optimization whose before/after
-    is recorded in EXPERIMENTS.md.
-    """
-    import os as _os
-    if _os.environ.get("REPRO_CACHE_SCATTER", "0") == "1":
-        B = buf.shape[0]
-        return buf.at[jnp.arange(B), lens].set(new[:, 0].astype(buf.dtype))
-    S = buf.shape[1]
-    onehot = jnp.arange(S)[None, :] == lens[:, None]          # (B,S)
-    oh = onehot.reshape(onehot.shape + (1,) * (buf.ndim - 2))
-    return jnp.where(oh, new.astype(buf.dtype), buf)
+    Only the B new rows are written, straight into the full buffer that
+    ``decode_step`` carries through its layer scan, so that with the cache
+    donated to the jitted step (``ServeSession``) XLA updates it in place.
+    A slot past the buffer (MLA decoding beyond S_max) writes nothing."""
+    B = new.shape[0]
+    buf = buf.at[i, jnp.arange(B), slot].set(new[:, 0].astype(buf.dtype))
+    return buf, jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
 
 
 def decode_step(cfg: ModelConfig, params, cache, token):
-    """token: (B, 1) int32. Returns (logits (B,1,V), new_cache)."""
+    """token: (B, 1) int32. Returns (logits (B,1,V), new_cache).
+
+    Each layer writes its one new K/V (or latent) row per sequence into the
+    carried cache (``_cache_write``); jit this step with the cache donated
+    and the update is in place."""
     dt = dtype_of(cfg.dtype)
     B = token.shape[0]
     h = params["embed"].astype(dt)[token]                     # (B,1,d)
@@ -415,46 +415,34 @@ def decode_step(cfg: ModelConfig, params, cache, token):
     if cfg.is_encoder_decoder:
         h = h + params["dec_pos"].astype(dt)[jnp.clip(pos, 0, 4095)][:, None]
 
-    def layer(h, xs):
-        p, layer_cache = xs
-        p = _cast(p, h.dtype)
-        x = rmsnorm(h, p["ln1"], cfg.norm_eps)
-        if cfg.mla is not None:
-            o, new_lc = _mla_decode_attn(p["attn"], x, cfg, layer_cache, pos)
-        else:
-            o, new_lc = _gqa_decode_attn(p["attn"], x, cfg, layer_cache, pos,
-                                         window)
-        h = h + o
-        if cfg.is_encoder_decoder:
-            x = rmsnorm(h, p["ln_cross"], cfg.norm_eps)
-            q = (x @ p["cross"]["wq"]).reshape(B, 1, cfg.num_heads,
-                                               cfg.resolved_head_dim)
-            xo = decode_attention(q, layer_cache["xk"], layer_cache["xv"],
-                                  jnp.full((B,), cfg.num_frames))
-            h = h + xo.reshape(B, 1, -1) @ p["cross"]["wo"]
-            new_lc["xk"], new_lc["xv"] = layer_cache["xk"], layer_cache["xv"]
-        x = rmsnorm(h, p["ln2"], cfg.norm_eps)
-        y, _ = ffn_block(p["ffn"], x, cfg)
-        return h + y, new_lc
-
-    layer_caches = {k: v for k, v in cache.items() if k != "pos"}
-
-    # Carry the cache through the scan and update layer i in place
-    # (dynamic_update_index): collecting per-layer caches as scan outputs
-    # would stack them into a SECOND full-cache buffer, defeating donation
-    # (measured 2x cache temp on the 67B decode cell — EXPERIMENTS.md §Perf).
+    # The cache rides in the scan's carry and each layer writes its rows
+    # into it: collecting per-layer caches as scan outputs would stack them
+    # into a SECOND full-cache buffer, defeating donation.
     def layer_carry(carry, xs):
         h, caches = carry
         p, i = xs
-        lc = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            caches)
-        h, new_lc = layer(h, (p, lc))
-        caches = jax.tree_util.tree_map(
-            lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                a, n.astype(a.dtype), i, 0), caches, new_lc)
-        return (h, caches), None
+        p = _cast(p, h.dtype)
+        x = rmsnorm(h, p["ln1"], cfg.norm_eps)
+        if cfg.mla is not None:
+            o, caches = _mla_decode_attn(p["attn"], x, cfg, caches, i, pos)
+        else:
+            o, caches = _gqa_decode_attn(p["attn"], x, cfg, caches, i, pos,
+                                         window)
+        h = h + o
+        if cfg.is_encoder_decoder:                # xk/xv: read-only here
+            x = rmsnorm(h, p["ln_cross"], cfg.norm_eps)
+            q = (x @ p["cross"]["wq"]).reshape(B, 1, cfg.num_heads,
+                                               cfg.resolved_head_dim)
+            xk, xv = (jax.lax.dynamic_index_in_dim(caches[n], i, 0,
+                                                   keepdims=False)
+                      for n in ("xk", "xv"))
+            xo = decode_attention(q, xk, xv, jnp.full((B,), cfg.num_frames))
+            h = h + xo.reshape(B, 1, -1) @ p["cross"]["wo"]
+        x = rmsnorm(h, p["ln2"], cfg.norm_eps)
+        y, _ = ffn_block(p["ffn"], x, cfg)
+        return (h + y, caches), None
 
+    layer_caches = {k: v for k, v in cache.items() if k != "pos"}
     (h, new_caches), _ = maybe_scan(
         layer_carry, (h, layer_caches),
         (params["blocks"], jnp.arange(cfg.num_layers)),
@@ -466,21 +454,21 @@ def decode_step(cfg: ModelConfig, params, cache, token):
     return logits, new_cache
 
 
-def _gqa_decode_attn(p, x, cfg, lc, pos, window):
+def _gqa_decode_attn(p, x, cfg, caches, i, pos, window):
     B = x.shape[0]
     KV, hd, H = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_heads
     q, k, v = _gqa_qkv(p, x, cfg, pos[:, None])
-    S = lc["k"].shape[1]
+    S = caches["k"].shape[2]
     slot = pos % S                        # ring buffer (id when S covers pos)
-    new_k = _cache_write(lc["k"], k, slot)
-    new_v = _cache_write(lc["v"], v, slot)
+    kbuf, new_k = _cache_write(caches["k"], i, k, slot)
+    vbuf, new_v = _cache_write(caches["v"], i, v, slot)
     eff_len = jnp.minimum(pos + 1, S)
     o = decode_attention(q, new_k, new_v, eff_len)
     o = o.reshape(B, 1, H * hd)
-    return o @ p["wo"], {"k": new_k, "v": new_v}
+    return o @ p["wo"], dict(caches, k=kbuf, v=vbuf)
 
 
-def _mla_decode_attn(p, x, cfg, lc, pos):
+def _mla_decode_attn(p, x, cfg, caches, i, pos):
     """Absorbed-form MLA decode: cache latent ckv + shared k_rope."""
     m = cfg.mla
     B = x.shape[0]
@@ -495,8 +483,8 @@ def _mla_decode_attn(p, x, cfg, lc, pos):
     ckv = rmsnorm(ckv, p["kv_norm_a"], cfg.norm_eps)
     k_rope = apply_rope(k_rope[:, :, None, :], pos[:, None], cfg.rope_theta)[:, :, 0]
 
-    new_ckv = _cache_write(lc["ckv"], ckv, pos)               # (B,S,kvr)
-    new_krope = _cache_write(lc["krope"], k_rope, pos)
+    ckv_buf, new_ckv = _cache_write(caches["ckv"], i, ckv, pos)  # (B,S,kvr)
+    krope_buf, new_krope = _cache_write(caches["krope"], i, k_rope, pos)
 
     wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim + m.v_head_dim)
     wk_b, wv_b = wkv_b[..., :m.qk_nope_head_dim], wkv_b[..., m.qk_nope_head_dim:]
@@ -514,7 +502,7 @@ def _mla_decode_attn(p, x, cfg, lc, pos):
     lat = jnp.einsum("bhs,bsr->bhr", pr, new_ckv.astype(jnp.float32))
     o = jnp.einsum("bhr,rhv->bhv", lat, wv_b.astype(jnp.float32))  # (B,H,v)
     o = o.reshape(B, 1, H * m.v_head_dim).astype(x.dtype)
-    return o @ p["wo"], {"ckv": new_ckv, "krope": new_krope}
+    return o @ p["wo"], dict(caches, ckv=ckv_buf, krope=krope_buf)
 
 
 def prefill(cfg: ModelConfig, params, tokens, S_max: int, *, frames=None,

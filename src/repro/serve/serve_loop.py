@@ -160,7 +160,10 @@ class ServeSession:
         self.B, self.S_max = batch_slots, S_max
         self.temperature = temperature
         self.rng = jax.random.PRNGKey(seed)
-        self._decode = jax.jit(lambda p, c, t: api.decode_step(p, c, t))
+        # the cache is donated: each step writes its new rows in place, and
+        # every caller rebinds ``cache`` to the step's result
+        self._decode = jax.jit(lambda p, c, t: api.decode_step(p, c, t),
+                               donate_argnums=(1,))
         try:
             sig = inspect.signature(api.prefill)
             self._ragged_ok = "prompt_lens" in sig.parameters

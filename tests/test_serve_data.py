@@ -1,13 +1,16 @@
 """Serving session + data pipeline."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config, reduce_config
 from repro.data.pipeline import DataPipeline
 from repro.configs.base import ShapeConfig
 from repro.models import build_model
-from repro.serve.serve_loop import ServeSession
+from repro.serve.serve_loop import Request, ServeSession
 
 CFG = reduce_config(get_config("qwen3-0.6b"))
 RNG = jax.random.PRNGKey(0)
@@ -32,6 +35,89 @@ def test_serve_session_matches_manual_greedy():
         manual.append(np.asarray(cur))
     manual = np.concatenate(manual, axis=1)
     assert outs == [list(map(int, r)) for r in manual]
+
+
+def _eager_greedy(api, params, prompts, max_new, S_max):
+    """Greedy tokens, and the last-position logits each was sampled from,
+    from the model's prefill and a plain eager loop of ``decode_step``: no
+    jit, no donation, every cache a fresh value."""
+    lens = [len(p) for p in prompts]
+    toks = np.zeros((len(prompts), max(lens)), np.int32)
+    for j, p in enumerate(prompts):
+        toks[j, :len(p)] = p
+    kw = {} if min(lens) == max(lens) else \
+        {"prompt_lens": jnp.asarray(lens, jnp.int32)}
+    logits, cache = api.prefill(params, jnp.asarray(toks), S_max, **kw)
+    out, seen = [], []
+    for step in range(max_new):
+        seen.append(np.asarray(logits[:, -1]))
+        cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+        out.append(np.asarray(cur))
+        if step < max_new - 1:
+            logits, cache = api.decode_step(params, cache, cur)
+    return np.concatenate(out, axis=1).tolist(), np.stack(seen)
+
+
+class _LogitsSession(ServeSession):
+    """A ``ServeSession`` that keeps the last-position logits of every
+    batch it samples from."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.seen = []
+
+    def _sample(self, logits):
+        self.seen.append(np.asarray(logits[:, -1]))
+        return super()._sample(logits)
+
+
+# (arch, prompt lengths, max_new, S_max, driver)
+DONATED_DECODE_CASES = {
+    "ragged": ("qwen3-0.6b", (9, 4, 7), 6, 16, "generate"),
+    # reduced Mixtral has an 8-slot window: positions 4..15 wrap the ring
+    "window_ring_wrap": ("mixtral-8x7b", (6, 4), 12, 32, "generate"),
+    "mla": ("deepseek-v3-671b", (5, 3), 5, 16, "generate"),
+    # two admission groups decode in turn, each on its own donated cache
+    "open_loop_two_groups": ("qwen3-0.6b", (6, 6, 6, 6), 16, 32,
+                             "open_loop"),
+}
+
+
+@pytest.mark.parametrize("case", list(DONATED_DECODE_CASES))
+def test_donated_decode_matches_eager_loop(case):
+    """``ServeSession``'s jitted decode step, which takes its cache donated
+    and writes one row per sequence per layer into it, serves the same
+    greedy tokens as an eager, undonated ``decode_step`` loop, from the
+    same logits (float32, so that the two differ by rounding alone)."""
+    arch, lens, max_new, S_max, driver = DONATED_DECODE_CASES[case]
+    cfg = dataclasses.replace(reduce_config(get_config(arch)),
+                              dtype="float32")
+    api = build_model(cfg)
+    params = api.init(RNG)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n) for n in lens]
+    sess = _LogitsSession(api, params, batch_slots=len(prompts),
+                          S_max=S_max)
+    if driver == "generate":
+        ref, ref_logits = _eager_greedy(api, params, prompts, max_new, S_max)
+        assert sess.generate(prompts, max_new=max_new) == ref
+        got = np.stack(sess.seen)
+        tol = 1e-4 * max(float(np.abs(ref_logits).max()), 1.0)
+        assert float(np.abs(got - ref_logits).max()) <= tol
+        return
+    half = len(prompts) // 2
+    reqs = [Request(prompt=p, max_new=max_new, arrival=0.0 if j < half
+                    else 5.0) for j, p in enumerate(prompts)]
+    rep = sess.serve_open_loop(reqs, step_cycles=1.0, prefill_cycles=1.0)
+    first, second = reqs[:half], reqs[half:]
+    adm = rep.admissions
+    assert len(set(adm[:half])) == len(set(adm[half:])) == 1
+    # the second group joins while the first still decodes
+    assert adm[half] < rep.completions[:half].min()
+    for group in (first, second):
+        ref, _ = _eager_greedy(api, params, [r.prompt for r in group],
+                               max_new, S_max)
+        assert [r.out for r in group] == ref
 
 
 def test_serve_batching_chunks_requests():

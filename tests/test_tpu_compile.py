@@ -10,6 +10,8 @@ The topology is described only inside the ``topo`` fixture, never while a
 module is imported: each pytest-xdist worker imports every test file, and
 only one process may hold the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,7 @@ from repro.kernels.act_clip import act_clip_count
 from repro.kernels.block_sparse_matmul import (block_sparse_matmul,
                                                build_tile_schedule)
 from repro.models import build_model
+from repro.serve.serve_loop import ServeSession
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -86,22 +89,54 @@ def test_act_clip_count_compiles_for_v5e(one_chip, shape, dtype):
     assert out[1].shape == (shape[0] // 256, shape[1] // 256)
 
 
-def test_qwen3_decode_step_fits_v5e_hbm(one_chip):
-    """The full-width Qwen3-0.6B decode step (B=8, S_max=1024) compiles
-    for one v5e chip and its program fits the chip's 16 GiB of HBM."""
-    cfg = get_config("qwen3-0.6b")
-    api = build_model(cfg)
-    B, S_max = 8, 1024
-
+def _decode_args(api, B, S_max, sharding):
+    """Shapes of (params, cache, token) of one decode step, on the chip."""
     def on_chip(tree):
         return jax.tree_util.tree_map(
-            lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+            lambda a: _spec(a.shape, a.dtype, sharding), tree)
 
     params = on_chip(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(lambda: api.init_cache(B, S_max)))
-    token = _spec((B, 1), jnp.int32, one_chip)
-    compiled = jax.jit(api.decode_step).lower(params, cache, token).compile()
+    return params, cache, _spec((B, 1), jnp.int32, sharding)
+
+
+def test_qwen3_decode_step_fits_v5e_hbm(one_chip):
+    """The full-width Qwen3-0.6B decode step (B=8, S_max=1024) compiles
+    for one v5e chip and its program fits the chip's 16 GiB of HBM."""
+    api = build_model(get_config("qwen3-0.6b"))
+    args = _decode_args(api, 8, 1024, one_chip)
+    compiled = jax.jit(api.decode_step).lower(*args).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, total
+
+
+def test_served_qwen3_decode_step_writes_its_cache_in_place(one_chip):
+    """The decode step as ``ServeSession`` jits it (the cache donated), at
+    the serve benchmark's shape (B=32, S_max=768): the output cache aliases
+    the donated input, no instruction copies the whole K or V buffer, and
+    no ``select`` rewrites a layer's whole (B, S, KV, hd) slice; each layer
+    writes only its B new rows."""
+    cfg = get_config("qwen3-0.6b")
+    api = build_model(cfg)
+    B, S_max = 32, 768
+    params, cache, token = _decode_args(api, B, S_max, one_chip)
+    sess = ServeSession(api, None, batch_slots=B, S_max=S_max)
+    compiled = sess._decode.lower(params, cache, token).compile()
+
+    kv_bytes = sum(cache[n].size * cache[n].dtype.itemsize for n in "kv")
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+
+    def producers(shape):
+        """Opcodes of the instructions that produce a bf16 ``shape``."""
+        dims = ",".join(map(str, shape))
+        return re.findall(rf"= bf16\[{dims}\]\{{[^}}]*\}} ([\w-]+)\(",
+                          compiled.as_text())
+
+    full = cache["k"].shape
+    assert full == (cfg.num_layers, B, S_max, cfg.num_kv_heads,
+                    cfg.resolved_head_dim)
+    assert "scatter" in producers(full)
+    assert not [op for op in producers(full) if op.startswith("copy")]
+    assert "select" not in producers(full[1:])
