@@ -9,6 +9,8 @@ of its own, found by its name:
   configs/<config>.json    sizes, the job that runs it, its source
   traffic/<traffic>.json   the mix's parameters, read by the job
   jobs/<job>.py            ``run(Run) -> Outcome``, one per job
+  families/<family>.py     a serve configuration's program side
+  reference/<family>.py    its plain reference and seeded weights
   metrics/<metric>.py      ``read(record) -> float | None``, one per metric
   checks/<workload>.json   the limit of each number ``correct`` compares
 """
@@ -57,6 +59,7 @@ class Cell:
     checks: Dict[str, dict]       # checks/<workload>.json: name -> limit
     end_to_end: List[dict]        # BENCHMARK.json metrics this cell reports
     per_layer: List[dict]
+    root: str = ROOT              # the checkout that holds the cell's files
 
 
 def _metrics_for(entries: List[dict], cell: str, e2e_names=None):
@@ -87,7 +90,7 @@ def load_cell(name: str, root: str = ROOT, bench: Optional[dict] = None
     per_layer = _metrics_for(bench["per_layer"], name,
                              {m["name"] for m in e2e})
     return Cell(name, int(w["chips"]), config, traffic, checks, e2e,
-                per_layer)
+                per_layer, root)
 
 
 def load_module(kind: str, name: str, root: str = ROOT):
@@ -171,6 +174,15 @@ class CompileCounter:
         import jax
         jax.monitoring.unregister_event_duration_listener(self._on)
         return False
+
+
+def span_seconds(tracer) -> Dict[str, float]:
+    """Seconds per span name over a ``repro.obs`` tracer's events; empty
+    where no tracer was installed."""
+    out: Dict[str, float] = {}
+    for e in (tracer.events if tracer else []):
+        out[e["name"]] = out.get(e["name"], 0.0) + e["t1"] - e["t0"]
+    return out
 
 
 # ---------------------------------------------------------------- runs

@@ -6,11 +6,21 @@ Every call gets fresh token ids drawn from the seed; all calls have the
 same sizes. The window closes at the first call boundary after
 ``--seconds``.
 
+What belongs to one architecture comes from the configuration's
+``family``, two files found by name:
+
+  families/<family>.py   ``program_config(cfg)``, the registry config that
+                         serves it, and ``request_flops(cfg, prompt_len,
+                         max_new)``, the record's ``flops``
+  reference/<family>.py  ``params(cfg, key)``, the seeded float32 masters
+                         in the program's layout, and ``make_logits(cfg,
+                         low_precision=False)``, the plain reference
+                         (float32 at ``highest``) and its control
+
 ``correct``: once the window has closed, a sample of the finished requests
 (``check_requests`` of them, one from each equal block of the slots, from
-calls drawn from the seed) is run through the plain reference
-(``reference/qwen3.py``, float32 at ``highest`` precision, weights
-regenerated from the seed), prompt and served tokens together:
+calls drawn from the seed) is run through the family's plain reference,
+on weights regenerated from the seed, prompt and served tokens together:
 
   served_logit_gap  the widest gap, over every sampled served token, by
                     which the reference's logit of the served token lies
@@ -18,35 +28,23 @@ regenerated from the seed), prompt and served tokens together:
 
 The served tokens come from the prefill (the first) and from the decode
 steps (the rest), so the gap covers both.
+
+Under ``--trace 1`` a ``repro.obs`` tracer is installed around the window:
+the record's ``span_s`` holds the seconds of each program span and
+``counters`` the tracer's counters at the window's end.
 """
 from __future__ import annotations
 
-import dataclasses
+import contextlib
 import gc
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench import costs, harness, weights
-from perfbench.reference import qwen3 as ref_qwen3
+from perfbench import harness, weights
 
 WARM_CALL = 2 ** 32       # the warm-up's ids: a call index never timed
-
-
-def program_config(cfg: dict):
-    """The registry's config at the file's sizes."""
-    from repro.configs import get_config
-    base = get_config(cfg["registry"])
-    return dataclasses.replace(
-        base, d_model=cfg["hidden_size"], d_ff=cfg["intermediate_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        vocab_size=cfg["vocab_size"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), act=cfg["hidden_act"],
-        tied_embeddings=bool(cfg["tie_word_embeddings"]),
-        qkv_bias=bool(cfg["attention_bias"]), dtype=cfg["torch_dtype"])
 
 
 def prompts(cfg: dict, traffic: dict, seed: int, call: int) -> np.ndarray:
@@ -70,15 +68,16 @@ def check_sample(seed: int, n_calls: int, slots: int, n: int) -> list:
     return out
 
 
-def gaps(cfg: dict, seed: int, prompt_rows, served_rows,
+def gaps(cfg: dict, reference, seed: int, prompt_rows, served_rows,
          low_precision: bool = False) -> np.ndarray:
-    """Per request, the widest gap between the reference's best logit and
-    its logit of the served token. With ``low_precision`` the served token
-    at each position is the one the float8 reference puts first (the
-    control: read on the same prompts and tokens, not decoded)."""
-    params = weights.lm_params(cfg, weights.seed_key(seed))
-    ref = ref_qwen3.make_logits(cfg)
-    ctl = ref_qwen3.make_logits(cfg, low_precision=True) \
+    """Per request, the widest gap between the ``reference`` module's best
+    logit and its logit of the served token. With ``low_precision`` the
+    served token at each position is the one the reference a precision
+    below puts first (the control: read on the same prompts and tokens,
+    not decoded)."""
+    params = reference.params(cfg, weights.seed_key(seed))
+    ref = reference.make_logits(cfg)
+    ctl = reference.make_logits(cfg, low_precision=True) \
         if low_precision else None
     out = []
     for p, y in zip(prompt_rows, served_rows):
@@ -95,21 +94,27 @@ def gaps(cfg: dict, seed: int, prompt_rows, served_rows,
 
 def run(run: harness.Run) -> harness.Outcome:
     from repro.models import build_model
+    from repro.obs import Tracer, use_tracer
     from repro.serve.serve_loop import ServeSession
 
     cfg, traffic = run.cell.config, run.cell.traffic
+    family = harness.load_module("families", cfg["family"], run.cell.root)
+    reference = harness.load_module("reference", cfg["family"],
+                                    run.cell.root)
     B, P, N = traffic["batch_slots"], traffic["prompt_len"], \
         traffic["max_new"]
-    api = build_model(program_config(cfg))
-    params = weights.lm_params(cfg, weights.seed_key(run.seed))
+    api = build_model(family.program_config(cfg))
+    params = reference.params(cfg, weights.seed_key(run.seed))
     sess = ServeSession(api, params, batch_slots=B, S_max=P + N)
 
     # warm-up: one call of the cell's own shape on ids of its own
     sess.generate(list(prompts(cfg, traffic, run.seed, WARM_CALL)), max_new=N)
 
+    tracer = Tracer() if run.trace else None
     prof = harness.Profiler(run.trace)
     calls = []                      # (start, end, prompts, outputs)
-    with harness.CompileCounter() as compiles, prof:
+    with harness.CompileCounter() as compiles, prof, \
+            use_tracer(tracer) if tracer else contextlib.nullcontext():
         with prof.annotate("perfbench.window"):
             t_start = harness.now()
             while True:
@@ -131,8 +136,10 @@ def run(run: harness.Run) -> harness.Outcome:
         "setup_s": t_start - run.t_process, "window_s": window_s,
         "calls": len(calls), "requests": B * len(calls),
         "output_tokens": B * N * len(calls),
-        "flops": len(calls) * B * costs.request_flops(cfg, P, N),
+        "flops": len(calls) * B * family.request_flops(cfg, P, N),
         "batch_slots": B, "prompt_len": P, "max_new": N, "config": cfg,
+        "span_s": harness.span_seconds(tracer),
+        "counters": dict(tracer.counters) if tracer else {},
         "trace": trace, "compiles_in_window": compiles.n,
         "device_kind": run.devices[0].device_kind,
     }
@@ -143,9 +150,11 @@ def run(run: harness.Run) -> harness.Outcome:
     jax.clear_caches()
     sel = check_sample(run.seed, len(calls), B, traffic["check_requests"])
     rows = ([calls[c][2][r] for c, r in sel], [calls[c][3][r] for c, r in sel])
-    values = {"served_logit_gap": float(gaps(cfg, run.seed, *rows).max())}
-    control = {"served_logit_gap": float(gaps(cfg, run.seed, *rows,
-                                              low_precision=True).max())} \
+    values = {"served_logit_gap":
+              float(gaps(cfg, reference, run.seed, *rows).max())}
+    control = {"served_logit_gap":
+               float(gaps(cfg, reference, run.seed, *rows,
+                          low_precision=True).max())} \
         if run.control else None
     return harness.Outcome(attempted=B * len(calls), failed=failed,
                            record=record,

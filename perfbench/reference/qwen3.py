@@ -6,12 +6,19 @@ grouped-query attention whose q and k heads get their own RMSNorm and
 rotary positions (rotate-half, base ``rope_theta``) and causal softmax at
 1/sqrt(head_dim), a residual add, a second RMSNorm, a SwiGLU feed-forward
 (silu(x Wg) * (x Wu)) Wd and a residual add; a final RMSNorm and the LM
-head tied to the embedding. The norm epsilon is the configuration file's.
+head: the embedding's transpose where ``tie_word_embeddings`` holds, else
+a (hidden, vocab) ``lm_head`` leaf of its own. The norm epsilon is the
+configuration file's.
 
 Float32 at ``highest`` matmul precision over the whole sequence at once,
 with no cache. For the control every matmul operand is rounded to float8
 (e4m3): weights under one absmax scale per tensor, activations under one
 per row. Imports nothing of the program.
+
+``params(cfg, key)`` gives the seeded float32 masters in the layout the
+program's entry points take (``weights.lm_params``, with an ``lm_head``
+leaf where the head is untied); the serve job hands the same weights to
+the program and to this reference.
 """
 from __future__ import annotations
 
@@ -19,6 +26,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from perfbench.weights import lm_params as params  # noqa: F401
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -50,6 +59,7 @@ def make_logits(cfg: dict, low_precision: bool = False):
     ``first + count`` (static ``count``)."""
     H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     hd, eps, theta = cfg["head_dim"], cfg["rms_norm_eps"], cfg["rope_theta"]
+    tied = bool(cfg["tie_word_embeddings"])
     G = H // KV
 
     if low_precision:
@@ -94,6 +104,6 @@ def make_logits(cfg: dict, low_precision: bool = False):
         h, _ = jax.lax.scan(block, h, params["blocks"])
         h = jax.lax.dynamic_slice_in_dim(h, first, count, axis=0)
         h = _rms(h, params["final_norm"], eps)
-        return mm(h, params["embed"].T)
+        return mm(h, params["embed"].T if tied else params["lm_head"])
 
     return logits

@@ -51,7 +51,10 @@ def cnn_params_and_images(cfg: dict, key, n_images: int):
 
 def lm_params(cfg: dict, key):
     """A Qwen3-style decoder's float32 master weights: LeCun-scaled
-    projections, norm scales near 1, embedding at 0.02 (tied LM head)."""
+    projections, norm scales near 1, embedding at 0.02. The LM head is
+    tied to the embedding; where ``tie_word_embeddings`` is false it is a
+    (hidden, vocab) ``lm_head`` leaf of its own, from a key the tied
+    weights do not use, so that they stay the same."""
     d, f = cfg["hidden_size"], cfg["intermediate_size"]
     L, H = cfg["num_hidden_layers"], cfg["num_attention_heads"]
     KV, hd, V = cfg["num_key_value_heads"], cfg["head_dim"], cfg["vocab_size"]
@@ -61,7 +64,7 @@ def lm_params(cfg: dict, key):
 
         def norm(shape):
             return 1.0 + _normal(next(k), shape, 0.1)
-        return {
+        params = {
             "embed": _normal(next(k), (V, d), 0.02),
             "final_norm": norm((d,)),
             "blocks": {
@@ -82,5 +85,8 @@ def lm_params(cfg: dict, key):
                 },
             },
         }
+        if not cfg["tie_word_embeddings"]:
+            params["lm_head"] = _normal(next(k), (d, V), d ** -0.5)
+        return params
 
     return jax.jit(make)(key)

@@ -150,3 +150,64 @@ def test_the_seed_gives_the_same_weights_and_traffic():
                           job.prompts(cfg, t, big, 3))
     assert job.prompts(cfg, t, big, 3).shape == (t["batch_slots"],
                                                  t["prompt_len"])
+
+
+FAMILY_DATA = os.path.join(ROOT, "tests", "perfbench", "data", "qwen3-untied")
+
+
+def _add_family(root, reference):
+    """Adds the test family, its config, traffic, checks and cell to the
+    tree at ``root`` as new files, and the cell's name to the lists of
+    ``BENCHMARK.json``; no file the tree had is edited."""
+    pb = root / "perfbench"
+    shutil.copy(os.path.join(FAMILY_DATA, "family.py"),
+                pb / "families" / "qwen3-untied.py")
+    shutil.copy(os.path.join(FAMILY_DATA, reference),
+                pb / "reference" / "qwen3-untied.py")
+    shutil.copy(os.path.join(FAMILY_DATA, "config.json"),
+                pb / "configs" / "qwen3-untied-tiny.json")
+    (pb / "traffic" / "tiny.json").write_text(
+        json.dumps(cells.SERVE_TRAFFIC))
+    name = "serve.qwen3-untied-tiny.tiny"
+    shutil.copy(pb / "checks" / "serve.qwen3-0.6b.decode.json",
+                pb / "checks" / f"{name}.json")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "qwen3-untied-tiny", "source": "test",
+        "file": "perfbench/configs/qwen3-untied-tiny.json", "reduced": [],
+        "why": "test"})
+    bench["workloads"].append({
+        "name": name, "config": "qwen3-untied-tiny", "traffic": "tiny",
+        "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("tokens_per_s", "mfu.serve"):
+            m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return name
+
+
+def _files(root):
+    return {os.path.relpath(os.path.join(d, f), root):
+            open(os.path.join(d, f), "rb").read()
+            for d, _, fs in os.walk(root) for f in fs
+            if f != "BENCHMARK.json"}
+
+
+@pytest.mark.parametrize("reference,correct", [
+    ("reference.py", True),
+    ("reference_tied_head.py", False),    # planted: the Qwen3 files' head
+])
+def test_a_new_family_enters_by_files_alone(tmp_path, reference, correct):
+    root = _copy_tree(tmp_path)
+    before = _files(root)
+    name = _add_family(root, reference)
+    after = _files(root)
+    assert all(after[k] == v for k, v in before.items())
+    cell = harness.load_cell(name, root=str(root))
+    assert cell.root == str(root)
+    assert [m["name"] for m in cell.end_to_end] == ["tokens_per_s",
+                                                    "setup_s"]
+    assert [m["name"] for m in cell.per_layer] == ["mfu.serve"]
+    out = cells.run(cell, seconds=0.3)
+    assert harness.is_correct(out) is correct, out.checks
+    assert out.record["flops"] > 0

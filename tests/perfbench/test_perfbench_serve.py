@@ -91,3 +91,66 @@ def test_a_fault_in_the_timed_path_is_not_correct(monkeypatch, fault):
     fault(monkeypatch)
     out = cells.run(_cell(), seconds=0.3)
     assert not harness.is_correct(out), out.checks
+
+
+def test_the_qwen3_family_gives_the_weights_of_weights_lm_params():
+    import jax
+    import numpy as np
+    from perfbench import weights
+    cfg = cells.small_qwen3()
+    key = weights.seed_key(2 ** 40 + 9)
+    got = harness.load_module("reference", "qwen3").params(cfg, key)
+    want = weights.lm_params(cfg, key)
+    assert jax.tree_util.tree_structure(got) \
+        == jax.tree_util.tree_structure(want)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_the_qwen3_family_counts_the_flops_of_costs_request_flops():
+    from perfbench import costs
+    cfg = harness.load_cell(DECODE).config
+    t = cells._json("perfbench/traffic/decode.json")
+    fam = harness.load_module("families", cfg["family"])
+    P, N = t["prompt_len"], t["max_new"]
+    assert fam.request_flops(cfg, P, N) == costs.request_flops(cfg, P, N)
+
+
+def test_traced_serve_records_the_program_spans_and_counters(monkeypatch):
+    real = harness.Profiler
+    monkeypatch.setattr(harness, "Profiler", lambda enabled: real(False))
+    cell = cells.cell(DECODE, cells.small_qwen3(), dict(cells.SERVE_TRAFFIC),
+                      cells.committed_checks(DECODE))
+    rec = cells.run(cell, seconds=0.3, trace=True).record
+    steps = rec["calls"] * (cells.SERVE_TRAFFIC["max_new"] - 1)
+    assert rec["span_s"]["serve.prefill"] > 0
+    assert rec["span_s"]["serve.decode"] > 0
+    assert rec["counters"]["serve.decode_steps"] == steps
+    rec = cells.run(cell, seconds=0.3, trace=False).record
+    assert rec["span_s"] == {} and rec["counters"] == {}
+
+
+def test_an_untied_head_adds_its_own_leaf_and_keeps_the_tied_weights():
+    import jax
+    import numpy as np
+    from perfbench import weights
+    cfg = cells.small_qwen3()
+    key = weights.seed_key(2 ** 40 + 11)
+    tied = weights.lm_params(cfg, key)
+    untied = weights.lm_params(dict(cfg, tie_word_embeddings=False), key)
+    head = untied.pop("lm_head")
+    assert head.shape == (cfg["hidden_size"], cfg["vocab_size"])
+    assert not np.allclose(head, tied["embed"].T)
+    for a, b in zip(jax.tree_util.tree_leaves(tied),
+                    jax.tree_util.tree_leaves(untied)):
+        assert np.array_equal(a, b)
+
+
+def test_span_seconds_sums_each_span_name():
+    class T:
+        events = [{"name": "a", "t0": 1.0, "t1": 1.5},
+                  {"name": "b", "t0": 2.0, "t1": 2.25},
+                  {"name": "a", "t0": 3.0, "t1": 3.5}]
+    assert harness.span_seconds(T()) == {"a": 1.0, "b": 0.25}
+    assert harness.span_seconds(None) == {}
