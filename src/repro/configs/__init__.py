@@ -17,6 +17,7 @@ from repro.configs.chameleon_34b import CONFIG as _chameleon_34b
 from repro.configs.rwkv6_1_6b import CONFIG as _rwkv6_1_6b
 from repro.configs.whisper_base import CONFIG as _whisper_base
 from repro.configs.zamba2_1_2b import CONFIG as _zamba2_1_2b
+from repro.configs.zamba2_7b import CONFIG as _zamba2_7b
 from repro.configs.paper_cnns import PAPER_CNNS
 
 ASSIGNED: Dict[str, ModelConfig] = {
@@ -27,6 +28,7 @@ ASSIGNED: Dict[str, ModelConfig] = {
     )
 }
 REGISTRY: Dict[str, ModelConfig] = dict(ASSIGNED)
+REGISTRY[_zamba2_7b.name] = _zamba2_7b          # served on the chip
 REGISTRY.update({c.name: c for c in PAPER_CNNS})
 
 

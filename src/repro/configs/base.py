@@ -41,6 +41,7 @@ class SSMConfig:
     expand: int = 2
     conv_dim: int = 4
     chunk: int = 64                      # chunked-scan length for training
+    ngroups: int = 1                     # B/C groups, each read by H/ngroups heads
 
 
 @dataclass(frozen=True)
@@ -72,8 +73,12 @@ class ModelConfig:
     # state-space / rwkv
     ssm: Optional[SSMConfig] = None
     rwkv: Optional[RWKVConfig] = None
-    # hybrid (zamba2): one *shared* attention block applied every k ssm layers
-    hybrid_attn_every: int = 0
+    # hybrid (zamba2): before each ssm layer in ``hybrid_layer_ids`` one of
+    # ``num_mem_blocks`` weight-tied transformer blocks runs (used in turn),
+    # its MLP with a rank-``adapter_rank`` adapter of that site's own
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
     # encoder-decoder (whisper)
     enc_layers: int = 0
     num_frames: int = 0                  # encoder sequence length (precomputed frames)
@@ -147,11 +152,8 @@ def cell_supported(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 # through the dry-run (ShapeDtypeStruct, no allocation).
 # ---------------------------------------------------------------------- #
 def reduce_config(cfg: ModelConfig) -> ModelConfig:
-    def _shrink(v, lo, hi):
-        return max(lo, min(v, hi))
-
     kw = {}
-    kw["num_layers"] = _shrink(cfg.num_layers, 2, 3 if cfg.hybrid_attn_every else 2)
+    kw["num_layers"] = 2
     kw["d_model"] = 64
     kw["num_heads"] = 4
     kw["num_kv_heads"] = max(1, min(cfg.num_kv_heads, 2)) if cfg.num_kv_heads < cfg.num_heads else 4
@@ -168,12 +170,15 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(cfg.moe, num_experts=4, top_k=2, expert_d_ff=32)
     if cfg.ssm is not None:
-        kw["ssm"] = SSMConfig(state_dim=8, head_dim=8, expand=2, conv_dim=4, chunk=8)
+        kw["ssm"] = SSMConfig(state_dim=8, head_dim=8, expand=2, conv_dim=4,
+                              chunk=8, ngroups=cfg.ssm.ngroups)
     if cfg.rwkv is not None:
         kw["rwkv"] = RWKVConfig(head_dim=16, decay_lora=8, mix_lora=4)
-    if cfg.hybrid_attn_every:
-        kw["hybrid_attn_every"] = 2
+    if cfg.hybrid_layer_ids:
+        kw["hybrid_layer_ids"] = (0, 2, 4)
         kw["num_layers"] = 5
+        kw["head_dim"] = 2 * kw["d_model"] // kw["num_heads"]
+        kw["adapter_rank"] = 4
     if cfg.family == "cnn":
         kw = {"img_res": 32, "num_classes": 11}
     return dataclasses.replace(cfg, **kw)

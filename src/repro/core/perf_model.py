@@ -336,9 +336,12 @@ def lm_layer_costs(cfg: ModelConfig, seq_len: int = 1,
         elif cfg.ssm is not None:
             s = cfg.ssm
             d_in = s.expand * d
-            add(f"{tag}.in_proj", d, 2 * d_in + 2 * s.state_dim + d_in // s.head_dim)
+            add(f"{tag}.in_proj", d,
+                2 * d_in + 2 * s.ngroups * s.state_dim + d_in // s.head_dim)
             add(f"{tag}.out_proj", d_in, d)
             attn_macs = d_in * s.state_dim * 2     # SSD state update per token
+            if i in cfg.hybrid_layer_ids:          # + the shared block's
+                attn_macs += H * hd * seq_len      # attention over the context
         else:
             add(f"{tag}.wq", d, H * hd)
             add(f"{tag}.wk", d, KV * hd)
@@ -360,9 +363,16 @@ def lm_layer_costs(cfg: ModelConfig, seq_len: int = 1,
             add(f"{tag}.w_gate", d, cfg.d_ff)
             add(f"{tag}.w_up", d, cfg.d_ff)
             add(f"{tag}.w_down", cfg.d_ff, d)
-        if cfg.hybrid_attn_every and i % cfg.hybrid_attn_every == 0:
-            add(f"{tag}.shared_qkvo", 2 * d, 4 * d)   # concat-proj + attn blk
-            add(f"{tag}.shared_ffn", d, 2 * cfg.d_ff)
+        if i in cfg.hybrid_layer_ids:
+            # the shared block before this layer: q/k/v over concat(h, emb)
+            # (2d -> (H + 2 KV) hd) and o (H hd -> d) as one linear of
+            # 2d x ((H + 2 KV) hd + H hd / 2); gate_up (d -> 2f) and down
+            # (f -> d) as one d x 3f; then this site's own adapter
+            # (d -> r -> 2f) and linear (d -> d)
+            add(f"{tag}.shared_qkvo", 2 * d, (H + 2 * KV) * hd + H * hd // 2)
+            add(f"{tag}.shared_ffn", d, 3 * cfg.d_ff)
+            add(f"{tag}.site_adapter", d + 2 * cfg.d_ff, cfg.adapter_rank)
+            add(f"{tag}.site_linear", d, d)
     add("unembed", d, cfg.vocab_size)
     return out
 

@@ -61,8 +61,13 @@ RULES: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
     (r"conv_b$", ("tp",)),
     (r"(A_log|D|dt_bias)$", ("tp",)),
     (r"out_norm$", ("tp",)),
-    # zamba shared block extras
-    (r"shared_proj$", ("fsdp", "tp")),
+    # zamba2 shared blocks (their attention matches the rules above) and
+    # each site's adapter and linear
+    (r"shared/w_gate_up$", ("fsdp", "tp")),
+    (r"shared/w_down$", ("tp", "fsdp")),
+    (r"sites/adapter_a$", ("fsdp", None)),
+    (r"sites/adapter_b$", (None, "tp")),
+    (r"sites/linear$", ("fsdp", "tp")),
     # mtp
     (r"mtp/proj$", ("fsdp", "tp")),
     # cnn

@@ -29,6 +29,24 @@ class ModelAPI:
     init_cache: Optional[Callable] = None
 
 
+# serving-cache leaves that hold keys and values (or their latents); every
+# other leaf but the positions is recurrent state
+KV_CACHE_LEAVES = ("k", "v", "ckv", "krope", "xk", "xv")
+
+
+def cache_bytes(cache: Dict[str, Any]) -> Dict[str, int]:
+    """Bytes of a serving cache's KV entries and of its recurrent-state
+    entries (an entry may hold a tuple of arrays, as Zamba2's state of
+    each segment), from their shapes and dtypes (no device sync)."""
+    out = {"kv": 0, "recurrent": 0}
+    for name, leaf in cache.items():
+        if name != "pos":
+            kind = "kv" if name in KV_CACHE_LEAVES else "recurrent"
+            out[kind] += sum(a.size * jnp.dtype(a.dtype).itemsize
+                             for a in jax.tree_util.tree_leaves(leaf))
+    return out
+
+
 def build_model(cfg: ModelConfig) -> ModelAPI:
     if cfg.family == "cnn":
         from repro.models import cnn

@@ -33,14 +33,16 @@ def _mask_bias(q_pos, k_pos, causal: bool, window: int):
 
 
 def reference_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                        kv_len: Optional[jnp.ndarray] = None):
-    """Naive O(S^2)-memory oracle. q:(B,Sq,H,D) k,v:(B,Sk,KV,D)."""
+                        kv_len: Optional[jnp.ndarray] = None, scale=None):
+    """Naive O(S^2)-memory oracle. q:(B,Sq,H,D) k,v:(B,Sk,KV,D). ``scale``
+    multiplies the scores (default 1/sqrt(D))."""
     B, Sq, H, D = q.shape
     KV = k.shape[2]
     Dv = v.shape[-1]
     G = H // KV
     qq = q.reshape(B, Sq, KV, G, D)
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qq.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     q_pos = q_offset + jnp.arange(Sq)
@@ -59,8 +61,9 @@ def reference_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 
 def blockwise_attention(q, k, v, *, causal=True, window=0, q_offset=0,
                         block_k=512, kv_len: Optional[jnp.ndarray] = None,
-                        impl="blockwise_full"):
+                        impl="blockwise_full", scale=None):
     """Flash-style attention. q:(B,Sq,H,D) k,v:(B,Sk,KV,D) -> (B,Sq,H,D).
+    ``scale`` multiplies the scores (default 1/sqrt(D)).
 
     impl:
       blockwise_full  scan over every KV block, masking (baseline)
@@ -71,7 +74,8 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     Dv = v.shape[-1]
     if Sk <= block_k * 2:
         return reference_attention(q, k, v, causal=causal, window=window,
-                                   q_offset=q_offset, kv_len=kv_len)
+                                   q_offset=q_offset, kv_len=kv_len,
+                                   scale=scale)
     if Sk % block_k:                                  # pad ragged KV, mask tail
         pad = block_k - Sk % block_k
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -81,8 +85,11 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, q_offset=0,
         Sk = Sk + pad
     G = H // KV
     nkb = Sk // block_k
-    qq = (q.reshape(B, Sq, KV, G, D).astype(jnp.float32)
-          / jnp.sqrt(D).astype(jnp.float32))
+    if scale is None:
+        qq = (q.reshape(B, Sq, KV, G, D).astype(jnp.float32)
+              / jnp.sqrt(D).astype(jnp.float32))
+    else:
+        qq = q.reshape(B, Sq, KV, G, D).astype(jnp.float32) * scale
     q_pos = q_offset + jnp.arange(Sq)
 
     if impl == "banded":

@@ -1,6 +1,7 @@
 """Shared model building blocks (pure JAX, no framework deps)."""
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -40,8 +41,8 @@ def rmsnorm(x, scale, eps=1e-5):
 def activation(name: str):
     if name == "silu":
         return jax.nn.silu
-    if name == "gelu":
-        return jax.nn.gelu
+    if name == "gelu":                        # exact (erf), as published
+        return functools.partial(jax.nn.gelu, approximate=False)
     if name == "relu2":                       # rwkv channel-mix
         return lambda x: jnp.square(jax.nn.relu(x))
     raise ValueError(name)

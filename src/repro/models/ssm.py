@@ -1,17 +1,28 @@
-"""Mamba2 (SSD) blocks and the Zamba2 hybrid (Mamba2 + shared attention).
+"""Mamba2 layers and the Zamba2 hybrid, after transformers'
+``modeling_zamba2.py`` (``Zamba2MambaMixer.torch_forward``,
+``Zamba2MambaDecoderLayer``, ``Zamba2HybridLayer``,
+``Zamba2AttentionDecoderLayer``, ``Zamba2MLP``).
 
-Mamba2 recurrence per head h (state in R^{hd x N}):
-    a_t = exp(-dt_t * exp(A_log))            (scalar per head)
-    H_t = a_t * H_{t-1} + (dt_t * x_t) ⊗ B_t
-    y_t = H_t · C_t + D ⊙ x_t
-with a depthwise causal conv (width 4) in front of x/B/C and a silu(z) gate.
+Mamba2 layer, heads h with state in R^{hd x N}, B and C in ``ngroups``
+groups (head h reads group h // (H / ngroups)):
+    [z | xBC | dt] = x W_in;   xBC = silu(causal_conv(xBC) + b)  (width K)
+    dt = max(softplus(dt + dt_bias), DT_MIN);   a = exp(-dt * exp(A_log))
+    H_t = a_t H_{t-1} + (dt_t x_t) ⊗ B_t;   y_t = H_t C_t + D x_t
+    out = (rmsnorm_per_group(y * silu(z)) * w) W_out
+Layer i: h <- h + mamba_i(rmsnorm(h + t_i)); t_i = 0 except at the sites.
 
-Zamba2 applies one *shared* (weight-tied) full-attention transformer block
-every ``hybrid_attn_every`` mamba layers; its input is proj(concat(h, h_emb0))
-per the Zamba recipe (per-invocation LoRA omitted — noted in DESIGN.md).
+Zamba2 hybrid: before layer ``hybrid_layer_ids[j]`` (site j) the
+weight-tied transformer block j % ``num_mem_blocks`` runs, with no residual
+of its own:
+    x = rmsnorm(concat(h, h_emb))                      (2 * d_model wide)
+    o = attention(x) W_o   (rotary positions, scale (head_dim / 2)^-0.5)
+    x = rmsnorm(o);   [g | u] = x W_gu + (x A_j) B_j   (site j's adapter)
+    t_j = ((gelu(g) * u) W_down) W_lin_j               (site j's ``linear``)
+where h_emb is the token's embedding. Each site keeps its own KV cache.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -20,27 +31,49 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.ctx import shard
 from repro.models import transformer as tfm
-from repro.models.common import (act_clip, dense_init, dtype_of, embed_init,
-                                 maybe_scan, rmsnorm)
+from repro.models.attention import NEG_INF, blockwise_attention
+from repro.models.common import (act_clip, activation, dense_init, dtype_of,
+                                 embed_init, maybe_scan, rmsnorm)
 
 Params = Dict[str, Any]
+
+DT_MIN = 1e-3            # Zamba2's ``time_step_min``: dt's floor
+GATED_NORM_EPS = 1e-5    # ``Zamba2RMSNormGated``'s eps, fixed in the source
+ATTN_WINDOW = 4096       # the shared blocks' KV is kept for the last 4096
+                         # positions, Zamba2's context (long_500k dry-run)
 
 
 def _dims(cfg: ModelConfig):
     s = cfg.ssm
     d_in = s.expand * cfg.d_model
-    H = d_in // s.head_dim
-    return d_in, H, s.head_dim, s.state_dim, s.conv_dim
+    return d_in, d_in // s.head_dim, s.head_dim, s.state_dim, s.conv_dim, \
+        s.ngroups
 
 
+def segments(cfg: ModelConfig):
+    """The (first, end) layers of the stack's segments: it is cut before
+    every site of ``hybrid_layer_ids``."""
+    cuts = sorted({0, cfg.num_layers, *cfg.hybrid_layer_ids})
+    return list(zip(cuts[:-1], cuts[1:]))
+
+
+def _take(tree, i):
+    """Layer ``i`` (a traced index) of a stacked pytree, read in place."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+# --------------------------------------------------------------------- #
+# Parameters
+# --------------------------------------------------------------------- #
 def init_mamba_params(cfg: ModelConfig, rng, L: int) -> Params:
     d = cfg.d_model
-    d_in, H, hd, N, K = _dims(cfg)
-    conv_ch = d_in + 2 * N
+    d_in, H, hd, N, K, G = _dims(cfg)
+    conv_ch = d_in + 2 * G * N
     ks = jax.random.split(rng, 8)
     return {
         "ln": jnp.ones((L, d)),
-        "in_proj": dense_init(ks[0], (L, d, 2 * d_in + 2 * N + H)),
+        "in_proj": dense_init(ks[0], (L, d, d_in + conv_ch + H)),  # z|xBC|dt
         "conv_w": dense_init(ks[1], (L, K, conv_ch), in_axis=-2),
         "conv_b": jnp.zeros((L, conv_ch)),
         "A_log": jnp.zeros((L, H)),
@@ -51,183 +84,242 @@ def init_mamba_params(cfg: ModelConfig, rng, L: int) -> Params:
     }
 
 
-def _causal_conv(x, w, b, state=None):
-    """Depthwise causal conv. x:(B,S,C), w:(K,C). state:(B,K-1,C) or None."""
-    K = w.shape[0]
-    if state is None:
-        pad = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
-    else:
-        pad = state.astype(x.dtype)
-    xp = jnp.concatenate([pad, x], axis=1)                   # (B,S+K-1,C)
-    out = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(K)) + b
-    return jax.nn.silu(out), xp[:, -(K - 1):]                # new conv state
-
-
-def mamba_block(p, x, cfg: ModelConfig, state=None, act_tau=None):
-    """x: (B,S,d). state: {'conv': (B,K-1,C), 'ssm': (B,H,hd,N)} or None."""
-    B, S, d = x.shape
-    d_in, H, hd, N, K = _dims(cfg)
-    x = act_clip(x, act_tau)
-    zxbcdt = x @ p["in_proj"]
-    z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * N], axis=-1)
-    conv_state = state["conv"] if state else None
-    xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
-    xs, Bc, Cc = jnp.split(xbc, [d_in, d_in + N], axis=-1)
-    xs = xs.reshape(B, S, H, hd)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])     # (B,S,H)
-    a = jnp.exp(-dt * jnp.exp(p["A_log"]))                          # (B,S,H)
-    dx = (dt[..., None] * xs.astype(jnp.float32))                   # (B,S,H,hd)
-
-    def step(Hst, inp):
-        a_t, dx_t, B_t, C_t = inp           # (B,H) (B,H,hd) (B,N) (B,N)
-        Hst = a_t[..., None, None] * Hst + \
-            jnp.einsum("bhd,bn->bhdn", dx_t, B_t.astype(jnp.float32))
-        y = jnp.einsum("bhdn,bn->bhd", Hst, C_t.astype(jnp.float32))
-        return Hst, y
-
-    H0 = state["ssm"] if state else jnp.zeros((B, H, hd, N), jnp.float32)
-    xs_t = tuple(jnp.moveaxis(v, 1, 0) for v in (a, dx, Bc, Cc))
-    H_new, ys = maybe_scan(step, H0, xs_t)
-    y = jnp.moveaxis(ys, 0, 1)                                      # (B,S,H,hd)
-    y = y + p["D"][:, None] * xs.astype(jnp.float32)
-    y = y.reshape(B, S, d_in).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
-    y = shard(y, "batch", None, "ff")
-    out = y @ p["out_proj"]
-    new_state = {"conv": new_conv, "ssm": H_new}
-    return out, new_state
-
-
-# --------------------------------------------------------------------- #
-# Zamba2 hybrid model
-# --------------------------------------------------------------------- #
-def _n_shared(cfg: ModelConfig) -> int:
-    return -(-cfg.num_layers // cfg.hybrid_attn_every)      # ceil
+def init_hybrid_params(cfg: ModelConfig, rng) -> Params:
+    """The shared blocks, stacked (num_mem_blocks, ...), and each site's
+    adapter and ``linear``, stacked (len(hybrid_layer_ids), ...)."""
+    M, n = cfg.num_mem_blocks, len(cfg.hybrid_layer_ids)
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    ks = jax.random.split(rng, 9)
+    shared = {
+        "ln1": jnp.ones((M, 2 * d)),
+        "attn": {"wq": dense_init(ks[0], (M, 2 * d, H * hd)),
+                 "wk": dense_init(ks[1], (M, 2 * d, KV * hd)),
+                 "wv": dense_init(ks[2], (M, 2 * d, KV * hd)),
+                 "wo": dense_init(ks[3], (M, H * hd, d))},
+        "ln2": jnp.ones((M, d)),
+        "w_gate_up": dense_init(ks[4], (M, d, 2 * f)),
+        "w_down": dense_init(ks[5], (M, f, d)),
+    }
+    sites = {"adapter_a": dense_init(ks[6], (n, d, r)),
+             "adapter_b": dense_init(ks[7], (n, r, 2 * f)),
+             "linear": dense_init(ks[8], (n, d, d))}
+    return {"shared": shared, "sites": sites}
 
 
 def init_params(cfg: ModelConfig, rng) -> Params:
-    ks = jax.random.split(rng, 6)
+    ks = jax.random.split(rng, 4)
     p: Params = {
         "embed": embed_init(ks[0], (cfg.vocab_size, cfg.d_model)),
         "mamba": init_mamba_params(cfg, ks[1], cfg.num_layers),
         "final_norm": jnp.ones((cfg.d_model,)),
     }
-    if cfg.hybrid_attn_every:
-        p["shared"] = tfm._block_params(ks[2], cfg, 1)      # one weight-tied block
-        p["shared_proj"] = dense_init(ks[3], (2 * cfg.d_model, cfg.d_model))
+    if cfg.hybrid_layer_ids:
+        p.update(init_hybrid_params(cfg, ks[2]))
     if not cfg.tied_embeddings:
-        p["lm_head"] = dense_init(ks[4], (cfg.d_model, cfg.vocab_size))
+        p["lm_head"] = dense_init(ks[3], (cfg.d_model, cfg.vocab_size))
     return p
 
 
-def _shared_attn(cfg, params, h, h0, positions, cache=None, pos=None,
-                 window=0, return_kv_eff=0, site=0):
-    """Apply the weight-tied attention block. cache: the KV of every
-    invocation site, (n_sites, B, S, KV, hd); decode writes site ``site``'s
-    rows into it and returns it whole.
-    return_kv_eff>0 (train path): also return the last ``eff`` K/V rows,
-    right-padded — the prefill cache for this invocation site."""
-    dt = h.dtype
-    p = tfm._cast(jax.tree_util.tree_map(lambda a: a[0], params["shared"]), dt)
-    z = jnp.concatenate([h, h0], axis=-1) @ params["shared_proj"].astype(dt)
-    x = rmsnorm(z, p["ln1"], cfg.norm_eps)
-    if cache is None:
-        o = tfm.attention_block(p["attn"], x, cfg, positions, causal=True)
-        new_cache = None
-        if return_kv_eff:
-            q, kk, vv = tfm._gqa_qkv(p["attn"], x, cfg, positions)
+# --------------------------------------------------------------------- #
+# Mamba2 layer
+# --------------------------------------------------------------------- #
+def _causal_conv(x, w, b, state=None):
+    """Depthwise causal conv, then silu. x:(B,S,C), w:(K,C), state:(B,K-1,C)
+    the K-1 inputs before x (zeros when None). Returns (out, the last K-1
+    inputs: the next conv state)."""
+    K = w.shape[0]
+    if state is None:
+        state = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
+    xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)   # (B,S+K-1,C)
+    f32 = xp.astype(jnp.float32)
+    out = sum(f32[:, i:i + x.shape[1]] * w[i].astype(jnp.float32)
+              for i in range(K)) + b.astype(jnp.float32)
+    return jax.nn.silu(out).astype(x.dtype), xp[:, -(K - 1):]
 
-            def to_cache(a):
-                eff = return_kv_eff
-                if a.shape[1] >= eff:
-                    return a[:, -eff:]
-                pad = [(0, 0)] * a.ndim
-                pad[1] = (0, eff - a.shape[1])
-                return jnp.pad(a, pad)
-            new_cache = {"k": to_cache(kk), "v": to_cache(vv)}
+
+def _gated_rmsnorm(y, z, w, groups: int):
+    """``Zamba2RMSNormGated``: y * silu(z), normalised over each of
+    ``groups`` equal slices of the last axis, times w (float32)."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    g = g.reshape(*g.shape[:-1], groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + GATED_NORM_EPS)
+    return g.reshape(y.shape) * w.astype(jnp.float32)
+
+
+def mamba_block(p, x, cfg: ModelConfig, state=None, act_tau=None):
+    """x: (B,S,d). state: {'conv': (B,K-1,C), 'ssm': (B,H,hd,N)} the state
+    before x, or None (zeros). Returns (out, the state after x)."""
+    B, S, _ = x.shape
+    d_in, H, hd, N, K, G = _dims(cfg)
+    f32 = jnp.float32
+    x = act_clip(x, act_tau)
+    z, xbc, dt = jnp.split(x @ p["in_proj"], [d_in, 2 * d_in + 2 * G * N],
+                           axis=-1)
+    xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"],
+                                 state["conv"] if state else None)
+    xs, Bc, Cc = jnp.split(xbc, [d_in, d_in + G * N], axis=-1)
+    dt = jnp.maximum(jax.nn.softplus(dt.astype(f32)
+                                     + p["dt_bias"].astype(f32)), DT_MIN)
+    a = jnp.exp(-dt * jnp.exp(p["A_log"].astype(f32)))              # (B,S,H)
+    xs = xs.astype(f32).reshape(B, S, G, H // G, hd)
+    dx = dt.reshape(B, S, G, H // G, 1) * xs
+    Bc = Bc.astype(f32).reshape(B, S, G, N)
+    Cc = Cc.astype(f32).reshape(B, S, G, N)
+
+    def step(st, inp):                      # st: (B,G,H/G,hd,N)
+        a_t, dx_t, B_t, C_t = inp
+        st = a_t[..., None, None] * st + \
+            dx_t[..., None] * B_t[:, :, None, None, :]
+        return st, jnp.einsum("bgkdn,bgn->bgkd", st, C_t)
+
+    st0 = state["ssm"].reshape(B, G, H // G, hd, N) if state else \
+        jnp.zeros((B, G, H // G, hd, N), f32)
+    a = a.reshape(B, S, G, H // G)
+    if S == 1:                              # decode: one step, no loop
+        st, y = step(st0, (a[:, 0], dx[:, 0], Bc[:, 0], Cc[:, 0]))
+        y = y[:, None]
     else:
-        o, new_cache = tfm._gqa_decode_attn(p["attn"], x, cfg, cache, site,
-                                            pos, window)
-    x2 = rmsnorm(z + o, p["ln2"], cfg.norm_eps)
-    y, _ = tfm.ffn_block(p["ffn"], x2, cfg)
-    return h + z + o + y, new_cache
+        st, ys = maybe_scan(step, st0, tuple(jnp.moveaxis(v, 1, 0)
+                                             for v in (a, dx, Bc, Cc)))
+        y = jnp.moveaxis(ys, 0, 1)                          # (B,S,G,H/G,hd)
+    y = y + p["D"].astype(f32).reshape(G, H // G, 1) * xs
+    y = _gated_rmsnorm(y.reshape(B, S, d_in), z, p["out_norm"], G)
+    y = shard(y.astype(x.dtype), "batch", None, "ff")
+    return y @ p["out_proj"], {"conv": new_conv,
+                               "ssm": st.reshape(B, H, hd, N)}
 
 
-def forward(cfg: ModelConfig, params, tokens, *, sparsity=None, remat=None,
-            state=None, return_state=False, S_max: int = 0):
+# --------------------------------------------------------------------- #
+# Shared transformer block
+# --------------------------------------------------------------------- #
+def _site_decode_attn(q, k, v, kv, j, pos, scale):
+    """One token's attention at site j. q (B,1,H,hd), k/v (B,1,KV,hd);
+    ``kv`` buffers (sites, B, S, KV * hd), in which each sequence's new row
+    is written at slot ``pos % S``. Returns (o (B,1,H * hd), kv).
+
+    The cache keeps each position's heads side by side in one row: with a
+    head of 224 a (.., KV, hd) buffer is stored by the chip with positions
+    minor, which the row write cannot use, so every step would copy the
+    whole buffer into a row layout and back. Each head's query is laid out
+    instead over its own kv head's columns of a row (zeros elsewhere), so
+    the scores and the weighted values are plain contractions over rows."""
+    B, _, H, hd = q.shape
+    KV = k.shape[2]
+    S = kv["k"].shape[2]
+    f32 = jnp.float32
+    rows = jnp.arange(B)
+    kv = {n: kv[n].at[j, rows, pos % S].set(a.reshape(B, -1).astype(
+        kv[n].dtype)) for n, a in (("k", k), ("v", v))}
+    own = jnp.repeat(jnp.eye(KV, dtype=f32), hd, axis=1)     # (KV, KV*hd)
+    own = jnp.repeat(own, H // KV, axis=0)                    # (H, KV*hd)
+    qq = jnp.tile(q[:, 0].astype(f32) * scale, (1, 1, KV)) * own
+    s = jnp.einsum("bhe,bse->bhs", qq, kv["k"][j].astype(f32))
+    valid = jnp.arange(S)[None, :] < jnp.minimum(pos + 1, S)[:, None]
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, NEG_INF), axis=-1)
+    o = jnp.einsum("bhs,bse->bhe", p, kv["v"][j].astype(f32)) * own
+    o = o.reshape(B, H, KV, hd).sum(axis=2)
+    return o.reshape(B, 1, H * hd).astype(q.dtype), kv
+
+
+def _shared_block(cfg: ModelConfig, params, j, h, emb, positions,
+                  kv=None, pos=None):
+    """Site j (static): block j % num_mem_blocks with site j's adapter and
+    linear. Returns (t_j, kv). ``kv`` {'k','v'}: (sites, B, S_max, KV * hd),
+    or empty (no cache); prefill (``pos`` None) writes the prompt's K/V at
+    site j, decode (``pos`` the (B,) positions, h one token) writes one row
+    per sequence."""
+    dt = h.dtype
+    blk = tfm._cast(jax.tree_util.tree_map(
+        lambda a: a[j % cfg.num_mem_blocks], params["shared"]), dt)
+    site = tfm._cast(jax.tree_util.tree_map(lambda a: a[j], params["sites"]),
+                     dt)
+    scale = (cfg.resolved_head_dim / 2) ** -0.5
+    B, S, _ = h.shape
+    x = rmsnorm(jnp.concatenate([h, emb], axis=-1), blk["ln1"], cfg.norm_eps)
+    if pos is None:
+        q, k, v = tfm._gqa_qkv(blk["attn"], x, cfg, positions)
+        o = blockwise_attention(q, k, v, causal=True, scale=scale)
+        o = o.reshape(B, S, -1) @ blk["attn"]["wo"]
+        if kv:
+            eff = kv["k"].shape[2]
+            kv = {n: kv[n].at[j].set(tfm._window(a, eff).reshape(
+                B, eff, -1).astype(kv[n].dtype))
+                for n, a in (("k", k), ("v", v))}
+    else:
+        q, k, v = tfm._gqa_qkv(blk["attn"], x, cfg, pos[:, None])
+        o, kv = _site_decode_attn(q, k, v, kv, j, pos, scale)
+        o = o @ blk["attn"]["wo"]
+    x = rmsnorm(o, blk["ln2"], cfg.norm_eps)
+    gu = x @ blk["w_gate_up"] + (x @ site["adapter_a"]) @ site["adapter_b"]
+    g, u = jnp.split(gu, 2, axis=-1)
+    y = (activation(cfg.act)(g) * u) @ blk["w_down"]
+    return y @ site["linear"], kv
+
+
+# --------------------------------------------------------------------- #
+# The layer stack
+# --------------------------------------------------------------------- #
+def _stack(cfg: ModelConfig, params, tokens, positions, state=None,
+           decode=False, sparsity=None, remat=None):
+    """Embedding and every layer. The stack is cut before each site
+    (``segments``): a segment that starts at site j first runs site j's
+    shared block, whose output t_j enters the segment's first layer, and
+    the segment's layers run in one scan. ``state`` (serving) holds each
+    segment's conv and SSM states, (layers, B, ...), which ride in its
+    scan's carry, and the sites' KV; each layer and each site writes its
+    own slice in place, as ``transformer.decode_step`` does with its cache.
+    ``decode``: one token per sequence at ``state['pos']``. Returns (h
+    before the final norm, new state)."""
     dt = dtype_of(cfg.dtype)
-    B, S = tokens.shape
     h = params["embed"].astype(dt)[tokens]
     h = shard(h, "batch", None, "embed")
-    h0 = h
-    positions = jnp.arange(S)
-    k = cfg.hybrid_attn_every
-    L = cfg.num_layers
-    d_in, Hh, hd, N, K = _dims(cfg)
+    emb = h                             # the shared blocks' second input
+    pos = state["pos"] if decode else None
+    ids = tuple(cfg.hybrid_layer_ids)
+    kv = {n: state[n] for n in ("k", "v") if state and n in state}
+    recurrent = {n: [] for n in ("conv", "ssm") if state and n in state}
 
-    def mamba_step(h, xs):
-        p, taus = xs
-        p = tfm._cast(p, dt)
-        f_tau = taus.get("ffn") if taus else None
-        x = rmsnorm(h, p["ln"], cfg.norm_eps)
-        if return_state:
-            zero = {"conv": jnp.zeros((B, K - 1, d_in + 2 * N), dt),
-                    "ssm": jnp.zeros((B, Hh, hd, N), jnp.float32)}
-            y, st = mamba_block(p, x, cfg, state=zero, act_tau=f_tau)
-            return h + y, st
-        y, _ = mamba_block(p, x, cfg, act_tau=f_tau)
-        return h + y, 0.0
+    def layer(carry, i):                # i: (the layer, its row in own)
+        h, t, own = carry
+        p = tfm._cast(_take(params["mamba"], i[0]), dt)
+        tau = _take(sparsity, i[0]).get("ffn") if sparsity else None
+        with jax.named_scope("mamba2"):
+            y, new = mamba_block(p, rmsnorm(h + t, p["ln"], cfg.norm_eps),
+                                 cfg, state=_take(own, i[1]) if own else None,
+                                 act_tau=tau)
+        own = {n: jax.lax.dynamic_update_index_in_dim(
+            a, new[n].astype(a.dtype), i[1], 0) for n, a in own.items()}
+        return (h + y, jnp.zeros_like(t), own), None
 
     if remat:
-        mamba_step = jax.checkpoint(mamba_step)
+        layer = jax.checkpoint(layer)
+    for seg, (lo, hi) in enumerate(segments(cfg)):
+        t = jnp.zeros_like(h)
+        if lo in ids:
+            with jax.named_scope("shared_block"):
+                t, kv = _shared_block(cfg, params, ids.index(lo), h, emb,
+                                      positions, kv, pos)
+        own = {n: state[n][seg] for n in recurrent}
+        (h, _, own), _ = maybe_scan(layer, (h, t, own),
+                                    (jnp.arange(lo, hi), jnp.arange(hi - lo)))
+        for n in recurrent:
+            recurrent[n].append(own[n])
+    return h, {**{n: tuple(v) for n, v in recurrent.items()}, **kv}
 
-    groups = [(g * k, min((g + 1) * k, L)) for g in range(_n_shared(cfg))] \
-        if k else [(0, L)]
-    states, attn_kv = [], []
-    eff = min(S_max or S, 4096)
-    for (lo, hi) in groups:
-        if k:
-            h, kv = _shared_attn(cfg, params, h, h0, positions,
-                                 return_kv_eff=eff if return_state else 0)
-            if return_state:
-                attn_kv.append(kv)
-        sub = jax.tree_util.tree_map(lambda a: a[lo:hi], params["mamba"])
-        taus = jax.tree_util.tree_map(lambda a: a[lo:hi], sparsity) \
-            if sparsity else None
-        if taus is None:
-            h, ys = maybe_scan(lambda c, p: mamba_step(c, (p, None)), h, sub,
-                               length=hi - lo)
-        else:
-            h, ys = maybe_scan(mamba_step, h, (sub, taus), length=hi - lo)
-        if return_state:
-            states.append(ys)
 
+def _logits(cfg: ModelConfig, params, h):
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     w = params["embed"].T if cfg.tied_embeddings else params["lm_head"]
-    logits = h @ w.astype(dt)
-    logits = shard(logits, "batch", None, "vocab")
-    if return_state:
-        full = jax.tree_util.tree_map(
-            lambda *a: jnp.concatenate(a, axis=0), *states) \
-            if len(states) > 1 else states[0]
-        st = {"conv": full["conv"], "ssm": full["ssm"],
-              "pos": jnp.full((B,), S, jnp.int32)}
-        if k:
-            st["attn_k"] = jnp.stack([kv["k"] for kv in attn_kv])
-            st["attn_v"] = jnp.stack([kv["v"] for kv in attn_kv])
-        return logits, st
-    return logits
+    return shard(h @ w.astype(h.dtype), "batch", None, "vocab")
 
 
-def prefill(cfg: ModelConfig, params, tokens, S_max: int, **kw):
-    """Parallel prefill: one forward over the prompt, states collected per
-    layer (mamba conv/ssm finals + windowed shared-attn KV)."""
-    B, S = tokens.shape
-    eff = min(S_max, 4096) if cfg.hybrid_attn_every else S_max
-    assert S <= eff or S % eff == 0, (S, eff)
-    logits, state = forward(cfg, params, tokens, return_state=True,
-                            S_max=S_max)
-    return logits[:, -1:], state
+def forward(cfg: ModelConfig, params, tokens, *, sparsity=None, remat=None):
+    """Teacher-forced logits (B, S, V) from zero state."""
+    h, _ = _stack(cfg, params, tokens, jnp.arange(tokens.shape[1]),
+                  sparsity=sparsity, remat=remat)
+    return _logits(cfg, params, h)
 
 
 def loss(cfg: ModelConfig, params, batch, *, sparsity=None, remat=None):
@@ -242,61 +334,43 @@ def loss(cfg: ModelConfig, params, batch, *, sparsity=None, remat=None):
 # Serving
 # --------------------------------------------------------------------- #
 def init_state(cfg: ModelConfig, B: int, S_max: int):
-    d_in, H, hd, N, K = _dims(cfg)
+    d_in, H, hd, N, K, G = _dims(cfg)
     L = cfg.num_layers
     dt = dtype_of(cfg.dtype)
+    segs = [hi - lo for lo, hi in segments(cfg)]
     st = {
-        "conv": jnp.zeros((L, B, K - 1, d_in + 2 * N), dt),
-        "ssm": jnp.zeros((L, B, H, hd, N), jnp.float32),
+        "conv": tuple(jnp.zeros((n, B, K - 1, d_in + 2 * G * N), dt)
+                      for n in segs),
+        "ssm": tuple(jnp.zeros((n, B, H, hd, N), jnp.float32) for n in segs),
         "pos": jnp.zeros((B,), jnp.int32),
     }
-    if cfg.hybrid_attn_every:
-        n = _n_shared(cfg)
+    if cfg.hybrid_layer_ids:
+        n = len(cfg.hybrid_layer_ids)
         KV, ahd = cfg.num_kv_heads, cfg.resolved_head_dim
-        eff = min(S_max, 4096)          # shared-attn KV windowed for long ctx
-        st["attn_k"] = jnp.zeros((n, B, eff, KV, ahd), dt)
-        st["attn_v"] = jnp.zeros((n, B, eff, KV, ahd), dt)
+        eff = min(S_max, ATTN_WINDOW)
+        st["k"] = jnp.zeros((n, B, eff, KV * ahd), dt)
+        st["v"] = jnp.zeros((n, B, eff, KV * ahd), dt)
     return st
 
 
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def prefill(cfg: ModelConfig, params, tokens, S_max: int):
+    """The prompt through every layer at once (the recurrence stepped over
+    its positions), from zero state. Returns (last logits (B,1,V), state)."""
+    B, S = tokens.shape
+    eff = min(S_max, ATTN_WINDOW)
+    assert S <= eff or S % eff == 0, (S, eff)
+    h, state = _stack(cfg, params, tokens, jnp.arange(S),
+                      state=init_state(cfg, B, S_max))
+    state["pos"] = jnp.full((B,), S, jnp.int32)
+    return _logits(cfg, params, h[:, -1:]), state
+
+
 def decode_step(cfg: ModelConfig, params, state, token):
-    dt = dtype_of(cfg.dtype)
-    B = token.shape[0]
-    h = params["embed"].astype(dt)[token]
-    pos = state["pos"]
-    h0 = h                 # Zamba: shared block sees the current-token embedding
-    k = cfg.hybrid_attn_every
-    L = cfg.num_layers
-    new_state = {"pos": pos + 1}
-
-    def mamba_step(carry, xs):
-        h = carry
-        p, st = xs
-        p = tfm._cast(p, dt)
-        y, new_st = mamba_block(p, rmsnorm(h, p["ln"], cfg.norm_eps), cfg,
-                                state=st)
-        return h + y, new_st
-
-    groups = [(g * k, min((g + 1) * k, L)) for g in range(_n_shared(cfg))] \
-        if k else [(0, L)]
-    new_conv, new_ssm = [], []
-    attn = {"k": state["attn_k"], "v": state["attn_v"]} if k else None
-    for gi, (lo, hi) in enumerate(groups):
-        if k:
-            h, attn = _shared_attn(cfg, params, h, h0, None, cache=attn,
-                                   pos=pos, window=4096, site=gi)
-        sub_p = jax.tree_util.tree_map(lambda a: a[lo:hi], params["mamba"])
-        sub_st = {"conv": state["conv"][lo:hi], "ssm": state["ssm"][lo:hi]}
-        h, sts = maybe_scan(mamba_step, h, (sub_p, sub_st), length=hi - lo)
-        new_conv.append(sts["conv"])
-        new_ssm.append(sts["ssm"])
-
-    new_state["conv"] = jnp.concatenate(new_conv, axis=0)
-    new_state["ssm"] = jnp.concatenate(new_ssm, axis=0)
-    if k:
-        new_state["attn_k"], new_state["attn_v"] = attn["k"], attn["v"]
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    w = params["embed"].T if cfg.tied_embeddings else params["lm_head"]
-    return h @ w.astype(dt), new_state
-
-
+    """token: (B, 1). Every layer writes its new conv and SSM state, and
+    every site its new K/V rows, into the state it was given (in place when
+    the state is donated, as ``ServeSession`` does)."""
+    h, new = _stack(cfg, params, token, state["pos"][:, None], state=state,
+                    decode=True)
+    new["pos"] = state["pos"] + 1
+    return _logits(cfg, params, h), new

@@ -505,6 +505,17 @@ def _mla_decode_attn(p, x, cfg, caches, i, pos):
     return o @ p["wo"], dict(caches, ckv=ckv_buf, krope=krope_buf)
 
 
+def _window(a, eff: int):
+    """K/V (B, S, ...) of a prompt as a cache of ``eff`` positions: the last
+    ``eff`` (position p at slot p % eff when S is a multiple of eff), or
+    the prompt right-padded with zeros."""
+    if a.shape[1] >= eff:
+        return a[:, -eff:]
+    pad = [(0, 0)] * a.ndim
+    pad[1] = (0, eff - a.shape[1])
+    return jnp.pad(a, pad)
+
+
 def prefill(cfg: ModelConfig, params, tokens, S_max: int, *, frames=None,
             attn_impl="blockwise_full", sparsity=None, prompt_lens=None):
     """Run the full prompt, build the cache. Returns (last_logits, cache).
@@ -532,14 +543,6 @@ def prefill(cfg: ModelConfig, params, tokens, S_max: int, *, frames=None,
         "ring-buffer slot arithmetic needs prompt len < cache or a multiple "
         f"of the window; got S={S}, eff={eff}")
 
-    def _to_cache(a):
-        """Keep the last ``eff`` positions; right-pad short prompts."""
-        if a.shape[1] >= eff:
-            return a[:, -eff:]
-        pad = [(0, 0)] * a.ndim
-        pad[1] = (0, eff - a.shape[1])
-        return jnp.pad(a, pad)
-
     def layer(h, xs):
         p, taus = xs
         p = _cast(p, h.dtype)
@@ -551,13 +554,14 @@ def prefill(cfg: ModelConfig, params, tokens, S_max: int, *, frames=None,
             q, k, v, ckv, k_rope = _mla_qkv(p["attn"], x, cfg, positions)
             o = blockwise_attention(q, k, v, causal=True, impl=attn_impl)
             o = o.reshape(B, S, -1) @ p["attn"]["wo"]
-            lc = {"ckv": _to_cache(ckv), "krope": _to_cache(k_rope[:, :, 0])}
+            lc = {"ckv": _window(ckv, eff),
+                  "krope": _window(k_rope[:, :, 0], eff)}
         else:
             q, k, v = _gqa_qkv(p["attn"], x, cfg, positions)
             o = blockwise_attention(q, k, v, causal=True,
                                     window=cfg.attn_window, impl=attn_impl)
             o = o.reshape(B, S, -1) @ p["attn"]["wo"]
-            lc = {"k": _to_cache(k), "v": _to_cache(v)}
+            lc = {"k": _window(k, eff), "v": _window(v, eff)}
         h = h + o
         if cfg.is_encoder_decoder:
             x = rmsnorm(h, p["ln_cross"], cfg.norm_eps)

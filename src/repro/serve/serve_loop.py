@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models import ModelAPI
+from repro.models import ModelAPI, cache_bytes
 from repro.obs.trace import get_tracer
 from repro.sim.trace import bucket_sizes
 
@@ -231,9 +231,13 @@ class ServeSession:
             kw = dict(kw, prompt_lens=jnp.asarray(lens, jnp.int32))
         # serve.prefill: host time to trace, lower and dispatch one prefill;
         # it does not wait for the device
-        with get_tracer().span("serve.prefill"):
+        tr = get_tracer()
+        with tr.span("serve.prefill"):
             logits, cache = self.api.prefill(self.params, jnp.asarray(toks),
                                              self.S_max, **kw)
+        if tr.enabled:
+            for kind, n in cache_bytes(cache).items():
+                tr.count(f"serve.cache_bytes:{kind}", n)
         return logits, cache, None
 
     def _decode_tokens(self, logits, cache, max_new: int) -> List[List[int]]:

@@ -27,7 +27,8 @@ def test_exact_assigned_dims():
     c = get_config("qwen3-0.6b")
     assert c.qk_norm and c.head_dim == 128
     c = get_config("zamba2-1.2b")
-    assert c.ssm.state_dim == 64 and c.hybrid_attn_every == 6
+    assert c.ssm.state_dim == 64 and \
+        c.hybrid_layer_ids == (0, 6, 12, 18, 24, 30, 36)
     c = get_config("whisper-base")
     assert c.enc_layers == 6 and c.vocab_size == 51865
 

@@ -65,13 +65,12 @@ def test_moe_active_expert_multiplier(arch, stacks):
 
 
 @pytest.mark.parametrize("arch", [a for a in ARCHS
-                                  if get_config(a).hybrid_attn_every])
+                                  if get_config(a).hybrid_layer_ids])
 def test_hybrid_shared_layers_at_cadence(arch, stacks):
-    """Hybrid (zamba-style) stacks interleave the shared attention block
-    every ``hybrid_attn_every`` ssm layers, starting at layer 0."""
+    """Hybrid (zamba-style) stacks price the shared block before each ssm
+    layer of ``hybrid_layer_ids`` and nowhere else."""
     cfg = get_config(arch)
-    expect = {i for i in range(cfg.num_layers)
-              if i % cfg.hybrid_attn_every == 0}
+    expect = set(cfg.hybrid_layer_ids)
     got = {int(l.name.split(".")[0][1:]) for l in stacks[arch]
            if ".shared_" in l.name}
     assert got == expect

@@ -77,6 +77,8 @@ DONATED_DECODE_CASES = {
     # reduced Mixtral has an 8-slot window: positions 4..15 wrap the ring
     "window_ring_wrap": ("mixtral-8x7b", (6, 4), 12, 32, "generate"),
     "mla": ("deepseek-v3-671b", (5, 3), 5, 16, "generate"),
+    # two alternating shared blocks; Mamba2 state and sites' KV donated
+    "hybrid": ("zamba2-7b", (6, 6), 5, 16, "generate"),
     # two admission groups decode in turn, each on its own donated cache
     "open_loop_two_groups": ("qwen3-0.6b", (6, 6, 6, 6), 16, 32,
                              "open_loop"),

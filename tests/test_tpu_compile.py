@@ -140,3 +140,59 @@ def test_served_qwen3_decode_step_writes_its_cache_in_place(one_chip):
     assert "scatter" in producers(full)
     assert not [op for op in producers(full) if op.startswith("copy")]
     assert "select" not in producers(full[1:])
+
+
+def _zamba2_first_stage():
+    """Zamba2-7B's first pipeline stage as the serve benchmark runs it:
+    24 layers with the sites 6, 11, 17, 23, bf16-stored weights."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("zamba2-7b"), num_layers=24,
+                              hybrid_layer_ids=(6, 11, 17, 23))
+    return cfg, build_model(cfg)
+
+
+def _bf16(tree):
+    return jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, jnp.bfloat16, a.sharding)
+        if a.dtype == jnp.float32 else a, tree)
+
+
+def test_served_zamba2_decode_step_fits_v5e_and_updates_its_state_in_place(
+        one_chip):
+    """The Zamba2 decode step as ``ServeSession`` jits it (state donated),
+    at the benchmark's shape (B=32, S_max=768): the program fits the
+    chip's HBM, its output state (conv and SSM state of every layer, the
+    sites' K and V) aliases the donated input, and no instruction copies a
+    whole state buffer into another layout."""
+    cfg, api = _zamba2_first_stage()
+    B, S_max = 32, 768
+    params, cache, token = _decode_args(api, B, S_max, one_chip)
+    sess = ServeSession(api, None, batch_slots=B, S_max=S_max)
+    compiled = sess._decode.lower(_bf16(params), cache, token).compile()
+    mem = compiled.memory_analysis()
+    leaves = [a for n in ("conv", "ssm", "k", "v")
+              for a in jax.tree_util.tree_leaves(cache[n])]
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in leaves)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, total
+    hlo = compiled.as_text()
+    for a in leaves:
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\{{[^}}]*\}} copy\(", hlo), \
+            a.shape
+
+
+def test_zamba2_prefill_fits_v5e_hbm(one_chip):
+    """The jitted Zamba2 prefill of the benchmark's batch (32 prompts of
+    512 tokens into an S_max of 768) fits one chip beside its weights."""
+    from repro.models import ssm
+    cfg, api = _zamba2_first_stage()
+    params, _, _ = _decode_args(api, 1, 1, one_chip)
+    tokens = _spec((32, 512), jnp.int32, one_chip)
+    mem = ssm.prefill.lower(cfg, _bf16(params), tokens, 768).compile() \
+        .memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, total
